@@ -27,6 +27,9 @@ def main() -> None:
     skip = set(filter(None, args.skip.split(",")))
 
     from benchmarks import kernel_bench, paper_tables, roofline_table
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     sections = [
         ("fig1", paper_tables.fig1_tightness_vs_time),

@@ -127,8 +127,7 @@ def row_block_policy(L: int) -> int:
     return min(D, max(64, -(-(D // 8) // 64) * 64))
 
 
-def band_step(d, carry, a2p, b2p, kk, *, L: int, w: int,
-              a_off=0, b_off=0):
+def band_step(d, carry, a_at, b_at, kk, *, L: int, w: int):
     """One anti-diagonal of the band-packed recurrence (no abandon test).
 
     ``carry = (S_{d-1}, S_{d-2})`` as ``(P, Wb)`` blocks; returns
@@ -138,18 +137,16 @@ def band_step(d, carry, a2p, b2p, kk, *, L: int, w: int,
     the per-lane diagonal-offset iota; lanes beyond ``2w`` (the kernel's
     128-multiple padding) are masked invalid.
 
-    ``a_off``/``b_off`` declare that ``a2p``/``b2p`` are *windows* of the
-    packed operands starting at those global columns (the streaming
-    kernel's double-buffered per-row-block windows); the resident callers
-    pass whole operands and leave the defaults at 0.  The arithmetic on
-    the window is identical — only the slice origin moves — so windowed
-    and resident sweeps stay bit-comparable by construction too.
+    ``a_at``/``b_at`` are the ``(P, Wb)`` operand windows of this
+    anti-diagonal: ``a2p[:, d : d + Wb]`` and ``b2p[:, 2L-1-d : ... + Wb]``
+    of the packed operands.  The caller loads them — ``lax.dynamic_slice``
+    here, an aligned ref load plus a lane rotate in the kernels (Mosaic
+    has no value-level dynamic lane slice) — so the window *values*, and
+    therefore every result, are the same on both paths.
     """
     d1, d2 = carry
     tp, Wb = d1.shape
     dt = d1.dtype
-    a_at = lax.dynamic_slice(a2p, (0, d - a_off), (tp, Wb))  # a[(d+k-w)//2]
-    b_at = lax.dynamic_slice(b2p, (0, 2 * L - 1 - d - b_off), (tp, Wb))
     diff = a_at - b_at
     cost = diff * diff
     inf_col = jnp.full((tp, 1), _INF, dt)
@@ -205,7 +202,9 @@ def _band_blocked_scan(
 
     def step(carry, d):
         (d1, d2), death, found = carry
-        nd, d1 = band_step(d, (d1, d2), a2p, b2p, kk, L=L, w=wb)
+        a_at = lax.dynamic_slice(a2p, (0, d), (P, Wb))
+        b_at = lax.dynamic_slice(b2p, (0, 2 * L - 1 - d), (P, Wb))
+        nd, d1 = band_step(d, (d1, d2), a_at, b_at, kk, L=L, w=wb)
         # abandon only at row-block boundaries (the kernel's grid layout)
         check = ((d + 1) % R == 0) | (d == D - 1)
         fmin = jnp.min(jnp.minimum(nd, d1), axis=-1, keepdims=True)

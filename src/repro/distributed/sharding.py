@@ -30,17 +30,11 @@ from repro.configs.base import ArchConfig, ShapeConfig
 
 
 def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (0.4.x spells it
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``)."""
-    if hasattr(jax, "shard_map"):                      # jax >= 0.6
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map   # jax 0.4.x
-    return shard_map(
+    """``jax.shard_map`` without the varying-manual-axes check (the
+    search and MoE steps mix replicated and per-shard values freely)."""
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 Array = jax.Array

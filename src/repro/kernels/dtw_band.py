@@ -18,8 +18,13 @@ The recurrence is pure lane shifts:
 with ``i = (d + k - w)/2`` (cells exist only at matching parity).  The cost
 operands are *contiguous* slices of the 2x-duplicated series
 ``A2[t] = a[t//2]`` and the flipped duplicate of ``b`` — both packed on the
-host, so each anti-diagonal step is two ``dynamic_slice`` calls plus a
-handful of ``(TP, Wb)`` VPU ops.
+host, so each anti-diagonal step is two windowed loads plus a handful of
+``(TP, Wb)`` VPU ops.  Mosaic loads only at 128-aligned dynamic lane
+offsets, so a window is an aligned ``Wb + 128``-lane load rotated into
+place (``_lane_window``); its values are those of the jnp mirror's
+``dynamic_slice``.  Pairs sit on the sublane axis everywhere, including
+the ``(tile_p, 1)`` cutoff and output blocks, so any multiple-of-8 pair
+tile is legal.
 
 Row-block early exit (this file's grid): PR 1's kernel poisoned abandoned
 lanes to +inf but still swept all ``2L - 1`` anti-diagonals per pair tile —
@@ -61,7 +66,7 @@ Streaming grid (``stream=True``): the resident grid above keeps the whole
 packed operands ``a2p``/``b2p`` (``~2 * TP * (2L + Wb)`` f32) in VMEM for
 the entire sweep, which is what used to cap ``dtw_band_op`` at
 ``_DTW_MAX_L = 16384``.  The streaming kernel removes the length ceiling
-by leaving the operands in HBM (``pltpu.ANY`` memory space) and turning
+by leaving the operands in HBM (``MemorySpace.ANY``) and turning
 the row-block grid into a true DMA pipeline: row block ``j`` only ever
 touches the operand windows ``a2p[:, jR : jR + R + Wb)`` and
 ``b2p[:, 2L - min(D, (j+1)R) : ... + R + Wb)``, so each ``(pair_tile,
@@ -70,7 +75,8 @@ copies are issued *before* block ``j``'s sweep and waited at the top of
 step ``j + 1``, overlapping DMA with compute everywhere except the
 warm-up block.  The DP frontier is carried in VMEM scratch exactly as in
 the resident grid, and the sweep runs the same ``band_step`` recurrence
-(with the window origins passed as ``a_off``/``b_off``), so streaming,
+(with the window origins, rounded down to 128 lanes for the DMA, passed
+as ``a_off``/``b_off``), so streaming,
 resident, and the jnp ``dtw_band_blocked`` reference stay bit-comparable
 by construction.  Two SMEM flags steer the pipeline: ``live`` (as in the
 resident grid) and ``pending`` (a DMA pair is in flight for the current
@@ -82,7 +88,8 @@ outputs.
 
 DMA-pipeline budget (per grid step — this is the whole point: the
 working set no longer contains ``L``): 2 double-buffer slots x 2 operand
-windows of ``Wwin = R + Wb`` lanes, plus the 2-buffer frontier and ~4
+windows of ``Wwin ~ R + Wb + 128`` lanes (``tiling.stream_window``), plus
+the 2-buffer frontier and ~4
 ``band_step`` temporaries of ``Wb`` lanes — ``(4 Wwin + ~8 Wb) * TP * 4``
 bytes, independent of series length.  ``tiling.stream_geometry`` picks
 the largest ``(tile_p, R)`` that fits ``_VMEM_BUDGET`` (preferring the
@@ -110,12 +117,47 @@ from repro.kernels.tiling import (
     pick_pair_tile,
     round_up,
     stream_geometry,
+    stream_window,
 )
 
 Array = jax.Array
 
 _INF = float(jnp.inf)
 _VMEM_BUDGET = 10 * 2**20          # bytes for packed operands + DP state
+
+
+def _lane_window(ref, off, width: int):
+    """``ref[:, off : off + width]`` at a traced lane offset ``off``.
+
+    Mosaic loads only at 128-aligned dynamic lane offsets, so load the
+    aligned ``width + 128``-lane window that covers the slice and rotate
+    the wanted lanes to its front: the same values ``lax.dynamic_slice``
+    gives the jnp mirror (core/dtw.py).  The operand must hold
+    ``round_down(off, 128) + width + 128`` lanes.
+    """
+    base = pl.multiple_of(off - lax.rem(off, 128), 128)
+    span = width + 128
+    win = ref[:, pl.ds(base, span)]
+    return pltpu.roll(win, lax.rem(span - (off - base), span), 1)[:, :width]
+
+
+def _band_sweep(a_ref, b_ref, d0, n_steps, carry, kk, *, L: int, w: int,
+                Wb: int, a_off=0, b_off=0):
+    """``n_steps`` anti-diagonals from ``d0`` over packed operand refs.
+
+    ``a_off``/``b_off`` are the global columns where ``a_ref``/``b_ref``
+    start (the streaming kernel's per-block windows; 0 for whole
+    operands), so each step reads ``a2p[:, d : d + Wb]`` and
+    ``b2p[:, 2L-1-d : ... + Wb]`` exactly as the jnp mirror slices them.
+    """
+
+    def step(t, c):
+        d = d0 + t
+        a_at = _lane_window(a_ref, d - a_off, Wb)
+        b_at = _lane_window(b_ref, 2 * L - 1 - d - b_off, Wb)
+        return band_step(d, c, a_at, b_at, kk, L=L, w=w)
+
+    return lax.fori_loop(0, n_steps, step, carry)
 
 
 def _dtw_band_kernel(a2p_ref, b2p_ref, cut_ref, out_ref, *, L: int, w: int,
@@ -125,15 +167,14 @@ def _dtw_band_kernel(a2p_ref, b2p_ref, cut_ref, out_ref, *, L: int, w: int,
     Kept as the ``early_exit=False`` path so the benchmark trajectory can
     measure the row-block grid against it.
     """
-    a2p = a2p_ref[...]                                   # (TP, pad_len)
-    b2p = b2p_ref[...]
-    cut = cut_ref[...][:, None]                          # (TP, 1)
-    tp = a2p.shape[0]
-    dt = a2p.dtype
+    cut = cut_ref[...]                                   # (TP, 1)
+    tp = cut.shape[0]
+    dt = out_ref.dtype
     kk = lax.broadcasted_iota(jnp.int32, (tp, Wb), 1)
 
     def step(d, carry):
-        nd, d1 = band_step(d, carry, a2p, b2p, kk, L=L, w=w)
+        nd, d1 = _band_sweep(a2p_ref, b2p_ref, d, 1, carry, kk, L=L, w=w,
+                             Wb=Wb)
         # every path crosses diagonal d or d-1 -> frontier min is a LB
         fmin = jnp.min(jnp.minimum(nd, d1), axis=-1, keepdims=True)
         dead = fmin > cut
@@ -143,7 +184,7 @@ def _dtw_band_kernel(a2p_ref, b2p_ref, cut_ref, out_ref, *, L: int, w: int,
 
     init = (jnp.full((tp, Wb), _INF, dt), jnp.full((tp, Wb), _INF, dt))
     dlast, _ = lax.fori_loop(0, 2 * L - 1, step, init)
-    out_ref[...] = dlast[:, w]
+    out_ref[...] = dlast[:, w:w + 1]
 
 
 def _dtw_band_kernel_blocked(a2p_ref, b2p_ref, cut_ref, out_ref,
@@ -167,18 +208,13 @@ def _dtw_band_kernel_blocked(a2p_ref, b2p_ref, cut_ref, out_ref,
 
     @pl.when(live_ref[0] == 1)
     def _sweep():
-        a2p = a2p_ref[...]                               # (TP, pad_len)
-        b2p = b2p_ref[...]
-        cut = cut_ref[...][:, None]                      # (TP, 1)
-        tp = a2p.shape[0]
+        cut = cut_ref[...]                               # (TP, 1)
+        tp = cut.shape[0]
         kk = lax.broadcasted_iota(jnp.int32, (tp, Wb), 1)
         d0 = j * R
         n_steps = jnp.minimum(R, D - d0)                 # last block is short
-
-        def step(t, carry):
-            return band_step(d0 + t, carry, a2p, b2p, kk, L=L, w=w)
-
-        d1, d2 = lax.fori_loop(0, n_steps, step, (s1_ref[...], s2_ref[...]))
+        d1, d2 = _band_sweep(a2p_ref, b2p_ref, d0, n_steps,
+                             (s1_ref[...], s2_ref[...]), kk, L=L, w=w, Wb=Wb)
         # block-boundary abandon: min(S_d, S_{d-1}) lower-bounds final DTW
         fmin = jnp.min(jnp.minimum(d1, d2), axis=-1, keepdims=True)
         dead = fmin > cut
@@ -188,7 +224,7 @@ def _dtw_band_kernel_blocked(a2p_ref, b2p_ref, cut_ref, out_ref,
 
     @pl.when(j == n_blocks - 1)
     def _emit():
-        out_ref[...] = s1_ref[...][:, w]
+        out_ref[...] = s1_ref[...][:, w:w + 1]
 
 
 def _dtw_band_kernel_stream(a2p_ref, b2p_ref, cut_ref, out_ref,
@@ -209,12 +245,19 @@ def _dtw_band_kernel_stream(a2p_ref, b2p_ref, cut_ref, out_ref,
     n_blocks = pl.num_programs(1)
     D = 2 * L - 1
 
-    def window_dmas(blk, slot):
+    def window_origins(blk):
         # block `blk` sweeps d in [blk*R, min(D, (blk+1)*R)): band_step
-        # slices a2p at d and b2p at 2L-1-d, so its operand windows are
-        # Wwin = R + Wb lanes starting at these offsets
+        # reads a2p from column d and b2p from 2L-1-d, so its windows
+        # start at these columns, rounded down to the 128-lane tiling a
+        # DMA and an aligned window load need (tiling.stream_window
+        # sizes Wwin to cover the rounding)
         aoff = blk * R
         boff = 2 * L - jnp.minimum(D, (blk + 1) * R)
+        return (pl.multiple_of(aoff - lax.rem(aoff, 128), 128),
+                pl.multiple_of(boff - lax.rem(boff, 128), 128))
+
+    def window_dmas(blk, slot):
+        aoff, boff = window_origins(blk)
         rows = pl.ds(i * TP, TP)
         da = pltpu.make_async_copy(
             a2p_ref.at[rows, pl.ds(aoff, Wwin)], abuf.at[slot],
@@ -259,19 +302,14 @@ def _dtw_band_kernel_stream(a2p_ref, b2p_ref, cut_ref, out_ref,
             db.start()
             flags_ref[1] = 1
 
-        a2w = abuf[slot]                                 # (TP, Wwin)
-        b2w = bbuf[slot]
-        cut = cut_ref[...][:, None]                      # (TP, 1)
+        cut = cut_ref[...]                               # (TP, 1)
         kk = lax.broadcasted_iota(jnp.int32, (TP, Wb), 1)
         d0 = j * R
-        boff = 2 * L - jnp.minimum(D, (j + 1) * R)
+        aoff, boff = window_origins(j)
         n_steps = jnp.minimum(R, D - d0)                 # last block is short
-
-        def step(t, carry):
-            return band_step(d0 + t, carry, a2w, b2w, kk, L=L, w=w,
-                             a_off=d0, b_off=boff)
-
-        d1, d2 = lax.fori_loop(0, n_steps, step, (s1_ref[...], s2_ref[...]))
+        d1, d2 = _band_sweep(abuf.at[slot], bbuf.at[slot], d0, n_steps,
+                             (s1_ref[...], s2_ref[...]), kk, L=L, w=w, Wb=Wb,
+                             a_off=aoff, b_off=boff)
         # block-boundary abandon: min(S_d, S_{d-1}) lower-bounds final DTW
         fmin = jnp.min(jnp.minimum(d1, d2), axis=-1, keepdims=True)
         dead = fmin > cut
@@ -281,7 +319,7 @@ def _dtw_band_kernel_stream(a2p_ref, b2p_ref, cut_ref, out_ref,
 
     @pl.when(j == n_blocks - 1)
     def _emit():
-        out_ref[...] = s1_ref[...][:, w]
+        out_ref[...] = s1_ref[...][:, w:w + 1]
 
 
 def _pack_band_operands(a: Array, b: Array, cutoff: Array | None, wb: int,
@@ -312,7 +350,7 @@ def _pack_band_operands(a: Array, b: Array, cutoff: Array | None, wb: int,
     zr = jnp.zeros((Pp, pad_len - wb - 2 * L), a.dtype)
     a2p = jnp.concatenate([zl, a2, zr], axis=-1)         # (Pp, pad_len)
     b2p = jnp.concatenate([zl, b2f, zr], axis=-1)
-    return a2p, b2p, cutoff, Pp
+    return a2p, b2p, cutoff[:, None], Pp
 
 
 @functools.partial(
@@ -363,12 +401,17 @@ def dtw_band_pallas(
             a, b, wb, cutoff, tile_p=tile_p, interpret=interpret,
             row_block=row_block,
         )
-    pad_len = round_up(2 * L + Wb + wb, 128)
+    # every aligned window load (_lane_window) stays inside the operand
+    pad_len = round_up(2 * L + Wb + max(wb, 128), 128)
     # auto-shrink the pair tile so packed operands + state fit VMEM
     per_row = (2 * pad_len + 8 * Wb) * 4
     tile_p = pick_pair_tile(tile_p, P, per_row, _VMEM_BUDGET)
     a2p, b2p, cutoff, Pp = _pack_band_operands(a, b, cutoff, wb, pad_len,
                                                tile_p)
+    # pairs on the sublane axis: (tile_p, 1) blocks stay legal for the
+    # scheduler's sub-128 pair tiles, where a rank-1 block would not
+    pair_spec = pl.BlockSpec((tile_p, 1), lambda i, *_: (i, 0))
+    out_shape = jax.ShapeDtypeStruct((Pp, 1), a.dtype)
     if not early_exit:
         out = pl.pallas_call(
             functools.partial(_dtw_band_kernel, L=L, w=wb, Wb=Wb),
@@ -376,13 +419,13 @@ def dtw_band_pallas(
             in_specs=[
                 pl.BlockSpec((tile_p, pad_len), lambda i: (i, 0)),
                 pl.BlockSpec((tile_p, pad_len), lambda i: (i, 0)),
-                pl.BlockSpec((tile_p,), lambda i: (i,)),
+                pair_spec,
             ],
-            out_specs=pl.BlockSpec((tile_p,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((Pp,), a.dtype),
+            out_specs=pair_spec,
+            out_shape=out_shape,
             interpret=interpret,
         )(a2p, b2p, cutoff)
-        return out[:P]
+        return out[:P, 0]
     D = 2 * L - 1
     R = row_block if row_block is not None else row_block_policy(L)
     R = max(1, min(R, D))
@@ -393,10 +436,10 @@ def dtw_band_pallas(
         in_specs=[
             pl.BlockSpec((tile_p, pad_len), lambda i, j: (i, 0)),
             pl.BlockSpec((tile_p, pad_len), lambda i, j: (i, 0)),
-            pl.BlockSpec((tile_p,), lambda i, j: (i,)),
+            pair_spec,
         ],
-        out_specs=pl.BlockSpec((tile_p,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Pp,), a.dtype),
+        out_specs=pair_spec,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((tile_p, Wb), a.dtype),
             pltpu.VMEM((tile_p, Wb), a.dtype),
@@ -404,7 +447,7 @@ def dtw_band_pallas(
         ],
         interpret=interpret,
     )(a2p, b2p, cutoff)
-    return out[:P]
+    return out[:P, 0]
 
 
 def _dtw_band_pallas_stream(
@@ -431,9 +474,10 @@ def _dtw_band_pallas_stream(
         )
     tile_p, R = geom
     n_blocks = -(-D // R)
-    Wwin = round_up(R + Wb, 128)
+    Wwin = stream_window(R, wb)
     # the host packing must cover every block window: block j reads
-    # a2p[:, jR : jR + Wwin) and b2p[:, 2L - min(D, (j+1)R) : ... + Wwin)
+    # Wwin lanes from jR and from 2L - min(D, (j+1)R), each rounded down
+    # to 128 lanes
     pad_len = round_up(
         max(2 * L + Wb + wb, (n_blocks - 1) * R + Wwin,
             2 * L - min(D, R) + Wwin),
@@ -441,18 +485,19 @@ def _dtw_band_pallas_stream(
     )
     a2p, b2p, cutoff, Pp = _pack_band_operands(a, b, cutoff, wb, pad_len,
                                                tile_p)
+    pair_spec = pl.BlockSpec((tile_p, 1), lambda i, j: (i, 0))
     out = pl.pallas_call(
         functools.partial(_dtw_band_kernel_stream, L=L, w=wb, Wb=Wb, R=R,
                           Wwin=Wwin, TP=tile_p),
         grid=(Pp // tile_p, n_blocks),
         in_specs=[
             # operands stay in HBM; the kernel DMAs its own windows
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec((tile_p,), lambda i, j: (i,)),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pair_spec,
         ],
-        out_specs=pl.BlockSpec((tile_p,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Pp,), a.dtype),
+        out_specs=pair_spec,
+        out_shape=jax.ShapeDtypeStruct((Pp, 1), a.dtype),
         scratch_shapes=[
             pltpu.VMEM((2, tile_p, Wwin), a.dtype),      # A2 window slots
             pltpu.VMEM((2, tile_p, Wwin), a.dtype),      # B2 window slots
@@ -464,4 +509,4 @@ def _dtw_band_pallas_stream(
         ],
         interpret=interpret,
     )(a2p, b2p, cutoff)
-    return out[:P]
+    return out[:P, 0]

@@ -56,72 +56,80 @@ Array = jax.Array
 _INF = float(jnp.inf)
 
 
-def _block_rows(q_ref, c_ref, u_ref, l_ref, out_ref, *, nb: int,
+def _block_rows(q_ref, ct_ref, u_ref, l_ref, out_ref, *, nb: int,
                 bands_only: bool, live=None):
-    """Write the (TQ, TC) bound block row by row (shared by the live-gated
-    and ungated kernel bodies); ``live`` masks dead candidate lanes to
-    ``-inf``."""
-    c = c_ref[...]            # (TC, L)
-    tq = q_ref.shape[0]
-    L = q_ref.shape[1]
+    """Write the (TQ, TC) bound block (shared by the live-gated and
+    ungated kernel bodies); ``live`` masks dead candidate lanes to
+    ``-inf``.
 
+    ``ct`` holds the candidates' band columns transposed, candidates on
+    lanes: rows ``0..nb-1`` are columns ``0..nb-1``, rows ``nb..2nb-1``
+    columns ``L-nb..L-1``.  Each band cell is then a ``(TQ, 1)`` query
+    column against a ``(1, TC)`` candidate row — a broadcast, where
+    reading single elements of a row would be a gather Mosaic cannot
+    lower.
+    """
+    q = q_ref[...]                                      # (TQ, L)
+    ct = ct_ref[...]                                    # (2 nb, TC)
+    tq, L = q.shape
+    acc = jnp.zeros(out_ref.shape, out_ref.dtype)
+    # --- elastic bands: unrolled static slices (nb is tiny) ---
+    for bi in range(nb):
+        # left band bi: cells (a_{bi-t}, b_bi) and (a_bi, b_{bi-t})
+        m = jnp.full(acc.shape, jnp.inf, dtype=acc.dtype)
+        for t in range(bi + 1):
+            d1 = q[:, bi - t:bi - t + 1] - ct[bi:bi + 1]
+            d2 = q[:, bi:bi + 1] - ct[bi - t:bi - t + 1]
+            m = jnp.minimum(m, jnp.minimum(d1 * d1, d2 * d2))
+        acc = acc + m
+        # right band (mirror around L-1): column L-1-s is ct row 2nb-1-s
+        ir, rr = L - 1 - bi, 2 * nb - 1 - bi
+        m = jnp.full(acc.shape, jnp.inf, dtype=acc.dtype)
+        for t in range(bi + 1):
+            d1 = q[:, ir + t:ir + t + 1] - ct[rr:rr + 1]
+            d2 = q[:, ir:ir + 1] - ct[rr + t:rr + t + 1]
+            m = jnp.minimum(m, jnp.minimum(d1 * d1, d2 * d2))
+        acc = acc + m
+    out_ref[...] = acc
+    # --- Keogh bridge over [nb, L - nb), one query row at a time ---
     if not bands_only:
-        u = u_ref[...]
-        lo = l_ref[...]
+        u = u_ref[:, nb:L - nb]
+        lo = l_ref[:, nb:L - nb]
 
-    def row(i, _):
-        qrow = q_ref[i, :]                              # (L,)
-        acc = jnp.zeros((c.shape[0],), dtype=out_ref.dtype)
-        # --- elastic bands: unrolled static slices (nb is tiny) ---
-        for bi in range(nb):
-            # left band bi: cells (a_j, b_bi) and (a_bi, b_k), j,k in [0, bi]
-            m = jnp.full((c.shape[0],), jnp.inf, dtype=acc.dtype)
-            for t in range(bi + 1):
-                d1 = qrow[bi - t] - c[:, bi]            # delta(a_{bi-t}, b_bi)
-                d2 = qrow[bi] - c[:, bi - t]            # delta(a_bi, b_{bi-t})
-                m = jnp.minimum(m, jnp.minimum(d1 * d1, d2 * d2))
-            acc = acc + m
-            # right band (mirror around L-1)
-            ir = L - 1 - bi
-            m = jnp.full((c.shape[0],), jnp.inf, dtype=acc.dtype)
-            for t in range(bi + 1):
-                d1 = qrow[ir + t] - c[:, ir]
-                d2 = qrow[ir] - c[:, ir + t]
-                m = jnp.minimum(m, jnp.minimum(d1 * d1, d2 * d2))
-            acc = acc + m
-        # --- Keogh bridge over [nb, L - nb) ---
-        if not bands_only:
-            qb = qrow[None, nb : L - nb]
-            over = jnp.maximum(qb - u[:, nb : L - nb], 0.0)
-            under = jnp.maximum(lo[:, nb : L - nb] - qb, 0.0)
-            acc = acc + jnp.sum(over * over + under * under, axis=-1)
-        out_ref[i, :] = acc if live is None else jnp.where(live, acc, -_INF)
-        return 0
+        def row(i, _):
+            qb = q_ref[pl.ds(i, 1), nb:L - nb]              # (1, L - 2nb)
+            over = jnp.maximum(qb - u, 0.0)
+            under = jnp.maximum(lo - qb, 0.0)
+            out_ref[i, :] = out_ref[i, :] + jnp.sum(
+                over * over + under * under, axis=-1)
+            return 0
 
-    lax.fori_loop(0, tq, row, 0, unroll=True)
+        lax.fori_loop(0, tq, row, 0, unroll=True)
+    if live is not None:
+        out_ref[...] = jnp.where(live, out_ref[...], -_INF)
 
 
 def _lb_enhanced_kernel(
-    q_ref, c_ref, u_ref, l_ref, out_ref, *, nb: int, bands_only: bool
+    q_ref, ct_ref, u_ref, l_ref, out_ref, *, nb: int, bands_only: bool
 ):
-    _block_rows(q_ref, c_ref, u_ref, l_ref, out_ref, nb=nb,
+    _block_rows(q_ref, ct_ref, u_ref, l_ref, out_ref, nb=nb,
                 bands_only=bands_only)
 
 
 def _lb_enhanced_kernel_live(
-    q_ref, c_ref, u_ref, l_ref, live_ref, out_ref, flag_ref, *, nb: int,
+    q_ref, ct_ref, u_ref, l_ref, live_ref, out_ref, flag_ref, *, nb: int,
     bands_only: bool
 ):
     """Live-gated candidate tile: dead candidates emit -inf columns,
     all-dead tiles skip the band/bridge compute entirely (SMEM flag +
     ``pl.when`` — the pairwise/DTW tiles' liveness mechanism)."""
-    live = live_ref[...] != 0                           # (TC,)
+    live = live_ref[...] != 0                           # (1, TC)
     flag_ref[0] = jnp.any(live).astype(jnp.int32)
     out_ref[...] = jnp.full(out_ref.shape, -_INF, out_ref.dtype)
 
     @pl.when(flag_ref[0] == 1)
     def _compute():
-        _block_rows(q_ref, c_ref, u_ref, l_ref, out_ref, nb=nb,
+        _block_rows(q_ref, ct_ref, u_ref, l_ref, out_ref, nb=nb,
                     bands_only=bands_only, live=live)
 
 
@@ -157,11 +165,15 @@ def lb_enhanced_pallas(
     tile_c = min(tile_c, C)
     if live is not None:
         live = jnp.broadcast_to(jnp.asarray(live), (C,)).astype(jnp.int32)
+    # band columns, transposed so candidates sit on lanes (_block_rows);
+    # one dummy row when there are no bands keeps the block non-empty
+    ct = (jnp.concatenate([c[:, :nb], c[:, L - nb:]], axis=1).T if nb
+          else jnp.zeros((1, C), c.dtype))
     pq, pc = (-Q) % tile_q, (-C) % tile_c
     if pq:
         q = jnp.pad(q, ((0, pq), (0, 0)))
     if pc:
-        c = jnp.pad(c, ((0, pc), (0, 0)))
+        ct = jnp.pad(ct, ((0, 0), (0, pc)))
         u = jnp.pad(u, ((0, pc), (0, 0)), constant_values=jnp.inf)
         lo = jnp.pad(lo, ((0, pc), (0, 0)), constant_values=-jnp.inf)
         if live is not None:
@@ -172,7 +184,7 @@ def lb_enhanced_pallas(
     out_shape = jax.ShapeDtypeStruct((Qp, Cp), q.dtype)
     in_specs = [
         pl.BlockSpec((tile_q, L), lambda i, j: (i, 0)),
-        pl.BlockSpec((tile_c, L), lambda i, j: (j, 0)),
+        pl.BlockSpec((ct.shape[0], tile_c), lambda i, j: (0, j)),
         pl.BlockSpec((tile_c, L), lambda i, j: (j, 0)),
         pl.BlockSpec((tile_c, L), lambda i, j: (j, 0)),
     ]
@@ -186,16 +198,16 @@ def lb_enhanced_pallas(
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
-        )(q, c, u, lo)
+        )(q, ct, u, lo)
     else:
         out = pl.pallas_call(
             functools.partial(_lb_enhanced_kernel_live, nb=nb,
                               bands_only=bands_only),
             grid=grid,
-            in_specs=in_specs + [pl.BlockSpec((tile_c,), lambda i, j: (j,))],
+            in_specs=in_specs + [pl.BlockSpec((1, tile_c), lambda i, j: (0, j))],
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
             interpret=interpret,
-        )(q, c, u, lo, live)
+        )(q, ct, u, lo, live[None, :])
     return out[:Q, :C]

@@ -59,30 +59,34 @@ _VMEM_BUDGET = 8 * 2**20           # bytes for the four (TP, L) operands
 
 def _bands_and_bridge(q_ref, c_ref, u_ref, l_ref, *, nb: int,
                       bands_only: bool, dt):
-    """(TP,) LB_ENHANCED^V accumulator for one pair tile (shared by the
-    live-gated and ungated kernel bodies)."""
+    """(TP, 1) LB_ENHANCED^V accumulator for one pair tile (shared by
+    the live-gated and ungated kernel bodies); pairs stay on the sublane
+    axis, like the (tile_p, 1) out and live blocks."""
     q = q_ref[...]                                      # (TP, L)
     c = c_ref[...]
     L = q.shape[1]
-    acc = jnp.zeros((q.shape[0],), dtype=dt)
+    acc = jnp.zeros((q.shape[0], 1), dtype=dt)
     # --- elastic bands: each arm is a contiguous column slice ---
     for bi in range(nb):
         ir = L - 1 - bi
         # left band bi: cells (a_t, b_bi) and (a_bi, b_t) for t <= bi
         dl1 = q[:, :bi + 1] - c[:, bi:bi + 1]
         dl2 = q[:, bi:bi + 1] - c[:, :bi + 1]
-        ml = jnp.min(jnp.minimum(dl1 * dl1, dl2 * dl2), axis=-1)
+        ml = jnp.min(jnp.minimum(dl1 * dl1, dl2 * dl2), axis=-1,
+                     keepdims=True)
         # right band (mirror around L-1): columns [ir, L)
         dr1 = q[:, ir:] - c[:, ir:ir + 1]
         dr2 = q[:, ir:ir + 1] - c[:, ir:]
-        mr = jnp.min(jnp.minimum(dr1 * dr1, dr2 * dr2), axis=-1)
+        mr = jnp.min(jnp.minimum(dr1 * dr1, dr2 * dr2), axis=-1,
+                     keepdims=True)
         acc = acc + ml + mr
     # --- Keogh bridge over [nb, L - nb) ---
     if not bands_only:
         qb = q[:, nb:L - nb]
         over = jnp.maximum(qb - u_ref[:, nb:L - nb], 0.0)
         under = jnp.maximum(l_ref[:, nb:L - nb] - qb, 0.0)
-        acc = acc + jnp.sum(over * over + under * under, axis=-1)
+        acc = acc + jnp.sum(over * over + under * under, axis=-1,
+                            keepdims=True)
     return acc
 
 
@@ -102,7 +106,7 @@ def _lb_enhanced_pairwise_kernel_live(
     """Live-gated tile: dead slots emit -inf, all-dead tiles skip the
     band/bridge compute entirely (SMEM flag + ``pl.when``, the DTW tiles'
     liveness mechanism)."""
-    live = live_ref[...] != 0                           # (TP,)
+    live = live_ref[...] != 0                           # (TP, 1)
     flag_ref[0] = jnp.any(live).astype(jnp.int32)
     out_ref[...] = jnp.full(out_ref.shape, -_INF, out_ref.dtype)
 
@@ -154,9 +158,12 @@ def lb_enhanced_pairwise_pallas(
             # pad slots are dead, so they never hold a tile's flag up
             live = jnp.pad(live, (0, pp))
     Pp = P + pp
-    out_shape = jax.ShapeDtypeStruct((Pp,), q.dtype)
+    # pairs on the sublane axis: (tile_p, 1) blocks, legal at any tile
+    out_shape = jax.ShapeDtypeStruct((Pp, 1), q.dtype)
     row_spec = pl.BlockSpec((tile_p, L), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((tile_p,), lambda i: (i,))
+    out_spec = pl.BlockSpec((tile_p, 1), lambda i: (i, 0))
+    if live is not None:
+        live = live[:, None]
     # single-tile batches drop the grid entirely: the tile is the whole
     # problem, so the grid scaffolding (index maps, per-step block
     # slicing) is pure overhead — this is what puts the kernel ahead of
@@ -192,11 +199,10 @@ def lb_enhanced_pairwise_pallas(
             out = pl.pallas_call(
                 kern,
                 grid=(Pp // tile_p,),
-                in_specs=[row_spec] * 4
-                + [pl.BlockSpec((tile_p,), lambda i: (i,))],
+                in_specs=[row_spec] * 4 + [out_spec],
                 out_specs=out_spec,
                 out_shape=out_shape,
                 scratch_shapes=scratch,
                 interpret=interpret,
             )(q, c, u, lo, live)
-    return out[:P]
+    return out[:P, 0]

@@ -2,11 +2,14 @@
 
 Dispatch policy:
   * on TPU backends the kernels run compiled (interpret=False);
-  * on CPU (this container) they run in ``interpret=True`` mode, which
-    executes the kernel bodies in Python for bit-faithful validation;
+  * on CPU they run in ``interpret=True`` mode, which executes the kernel
+    bodies in Python for bit-faithful validation;
+  * any other backend raises: there is no silent third path;
   * shapes outside kernel limits (very long series that exceed the VMEM
-    budget documented in each kernel) fall back to the pure-jnp reference,
-    so the public API never fails on shape grounds.
+    budget documented in each kernel) fall back to the pure-jnp reference
+    with a ``KernelFallbackWarning`` (once per kernel and shape), so the
+    public API never fails on shape grounds and a fallback is never
+    silent.
 
 All entry points accept/return plain arrays and are safe to ``jax.jit``
 (and to call inside ``shard_map`` — see search/distributed.py).
@@ -15,6 +18,7 @@ All entry points accept/return plain arrays and are safe to ``jax.jit``
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -41,8 +45,32 @@ _LB_MAX_L = 16384
 _DTW_RESIDENT_MAX_L = 16384
 
 
+class KernelFallbackWarning(UserWarning):
+    """A kernel op served a shape through its jnp reference instead."""
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret the kernels on CPU, compile them on TPU, refuse the rest."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on tpu or interpreted on cpu; "
+            f"the default backend is {backend!r}"
+        )
+    return backend == "cpu"
+
+
+def _fallback(kernel: str, shape, why: str) -> None:
+    """Warn that ``kernel`` serves ``shape`` through its jnp reference.
+
+    The message names the kernel and the shape, so the warnings module's
+    per-message registry shows it once per kernel and shape.
+    """
+    warnings.warn(
+        f"{kernel}: shape {tuple(shape)} served by the jnp reference ({why})",
+        KernelFallbackWarning,
+        stacklevel=3,
+    )
 
 
 def envelope_op(b: Array, w: int) -> tuple[Array, Array]:
@@ -52,6 +80,7 @@ def envelope_op(b: Array, w: int) -> tuple[Array, Array]:
     if squeeze:
         b = b[None]
     if b.shape[-1] > _ENVELOPE_MAX_L:
+        _fallback("envelope", b.shape, f"L > {_ENVELOPE_MAX_L}")
         u, lo = ref.envelope_ref(b, w)
     else:
         u, lo = envelope_pallas(b, w, interpret=_interpret())
@@ -61,6 +90,7 @@ def envelope_op(b: Array, w: int) -> tuple[Array, Array]:
 def lb_keogh_op(q: Array, u: Array, lo: Array) -> Array:
     """``(Q, L) x (C, L) envelopes -> (Q, C)`` LB_KEOGH matrix."""
     if q.shape[-1] > _LB_MAX_L:
+        _fallback("lb_keogh", q.shape, f"L > {_LB_MAX_L}")
         return ref.lb_keogh_ref(q, u, lo)
     return lb_keogh_pallas(q, u, lo, interpret=_interpret())
 
@@ -78,6 +108,7 @@ def lb_enhanced_op(
     cross-block tier too (see kernels/lb_enhanced.py).
     """
     if q.shape[-1] > _LB_MAX_L:
+        _fallback("lb_enhanced", q.shape, f"L > {_LB_MAX_L}")
         return ref.lb_enhanced_ref(
             q, c, u, lo, w, v, live=live, bands_only=bands_only
         )
@@ -103,6 +134,7 @@ def lb_enhanced_pairwise_op(
     limits become skipped work, not masked outputs.
     """
     if q.shape[-1] > _LB_MAX_L:
+        _fallback("lb_enhanced_pairwise", q.shape, f"L > {_LB_MAX_L}")
         return ref.lb_enhanced_pairwise_ref(
             q, c, u, lo, w, v, live=live, bands_only=bands_only
         )
@@ -110,11 +142,6 @@ def lb_enhanced_pairwise_op(
         q, c, u, lo, w, v, live=live, bands_only=bands_only,
         interpret=_interpret(),
     )
-
-
-# the sketch kernel holds the (Q, S) query block resident per tile;
-# beyond this many queries the op batches the reference instead
-_SKETCH_MAX_Q = 4096
 
 
 def sketch_bound_op(
@@ -129,9 +156,6 @@ def sketch_bound_op(
     scaled-units form — the kernel never sees ``sk_scale``.
     """
     qbar = jnp.asarray(qbar, jnp.float32)
-    if qbar.shape[0] > _SKETCH_MAX_Q:
-        return ref.sketch_bound_ref(qbar, sk_lo, sk_hi, sk_scale,
-                                    seg_sizes)
     scale = jnp.asarray(sk_scale, jnp.float32)
     qs = qbar / scale
     wseg = jnp.asarray(seg_sizes, jnp.float32) * scale * scale
@@ -188,6 +212,8 @@ def dtw_band_op(
     if L > _DTW_RESIDENT_MAX_L:
         wb = min(L if (w is None or w >= L) else w, L - 1)
         if stream_geometry(L, wb, tp, P, _DTW_VMEM_BUDGET) is None:
+            _fallback("dtw_band", a.shape,
+                      f"band state of w={wb} exceeds VMEM")
             out = ref.dtw_band_ref(a, b, w, cutoff)
         else:
             out = dtw_band_pallas(

@@ -63,17 +63,21 @@ def pick_pair_tile(tile_p: int, P: int, per_row_bytes: int,
     return min(tile_p, round_up(P, 8))
 
 
-def sketch_tile_c(Q: int, S: int, C: int, budget_bytes: int) -> int:
-    """Candidate-tile width for the sketch kernel (kernels/sketch.py).
+# sketch kernel output block: queries on sublanes, candidates on lanes
+_SKETCH_TILE_Q = 128
+_SKETCH_TILE_C = 512
 
-    Per candidate: ``2 S`` int8 feature cells, their f32 in-register
-    casts (``8 S``), and the ``(Q, tile)`` output column (``4 Q``).
-    Lane multiples of 128, floor 128, clamped so a short store is a
-    single tile.
+
+def sketch_tiles(Q: int, C: int) -> tuple[int, int]:
+    """``(tile_q, tile_c)`` output block of the sketch kernel
+    (kernels/sketch.py): queries on sublanes (multiples of 8), candidates
+    on lanes (multiples of 128), clamped so a short batch or store is a
+    single tile.  The block is fixed, not budget-sized: the kernel
+    unrolls its segment loop over the whole block, so a wider block only
+    lengthens the program (and its compile) without saving HBM traffic.
     """
-    per_c = 2 * S + 8 * S + 4 * Q
-    tile = max(128, (budget_bytes // max(1, per_c)) // 128 * 128)
-    return min(tile, round_up(C, 128))
+    return (min(_SKETCH_TILE_Q, round_up(Q, 8)),
+            min(_SKETCH_TILE_C, round_up(C, 128)))
 
 
 def sched_pair_tile(P: int, default: int = 128) -> int:
@@ -150,9 +154,9 @@ def stream_geometry(
     """Per-block working-set budget for the streaming DTW kernel.
 
     The streaming kernel's VMEM footprint is *per row block*, not per
-    sweep: 2 double-buffer slots x 2 operand windows of ``Wwin = R + Wb``
-    lanes plus the frontier/temporary state of ``~8 Wb`` lanes, all times
-    the pair tile.  Returns ``(tile, R)`` — the largest pair tile (sublane
+    sweep: 2 double-buffer slots x 2 operand windows of
+    ``stream_window(R, wb)`` lanes plus the frontier/temporary state of
+    ``~8 Wb`` lanes, all times the pair tile.  Returns ``(tile, R)`` — the largest pair tile (sublane
     multiples, floor 8) and row block (64-step multiples) that fit
     ``budget_bytes`` — or ``None`` when even the minimum block at the
     sublane floor cannot fit (the band state itself exceeds VMEM; ops.py
@@ -174,7 +178,7 @@ def stream_geometry(
         row_block_policy(L), min(pref, D))
     R = max(1, min(R, D))
     while True:
-        Wwin = round_up(R + Wb_pad(wb), 128)
+        Wwin = stream_window(R, wb)
         per_row = (4 * Wwin + 8 * Wb_pad(wb)) * 4
         tile = pick_pair_tile(tile_p, P, per_row, budget_bytes)
         if tile * per_row <= budget_bytes:
@@ -182,6 +186,17 @@ def stream_geometry(
         if R <= _STREAM_MIN_BLOCK:
             return None
         R = max(_STREAM_MIN_BLOCK, round_up(R // 2, _STREAM_MIN_BLOCK))
+
+
+def stream_window(R: int, wb: int) -> int:
+    """Lanes of one streaming operand window for row block ``R``.
+
+    A block's sweep reads ``R + Wb`` lanes from its origin; the kernel
+    rounds the origin down to the 128-lane tiling (up to 127 more lanes)
+    and reads each step's band through a ``Wb + 128``-lane aligned load
+    (kernels/dtw_band.py ``_lane_window``), which this width covers.
+    """
+    return round_up(R + 127, 128) + Wb_pad(wb)
 
 
 def Wb_pad(wb: int) -> int:

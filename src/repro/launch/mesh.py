@@ -12,11 +12,9 @@ import math
 import jax
 
 
-def _axis_types_kw(n: int) -> dict:
-    """``axis_types=Auto`` where the installed jax knows it (>= 0.5),
-    nothing on older versions (Auto is their only behaviour anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return {} if axis_type is None else {"axis_types": (axis_type.Auto,) * n}
+def _auto_axes(n: int) -> tuple:
+    """Every mesh axis ``Auto``: the compiler propagates the shardings."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -36,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
         shape,
         axes,
         devices=devices,
-        **_axis_types_kw(len(axes)),
+        axis_types=_auto_axes(len(axes)),
     )
 
 
@@ -49,5 +47,5 @@ def make_host_mesh(
         shape,
         axes,
         devices=jax.devices()[:n],
-        **_axis_types_kw(len(axes)),
+        axis_types=_auto_axes(len(axes)),
     )

@@ -51,23 +51,10 @@ plus O(Q * k * shards) floats for the top-k merge — independent of both N
 and L — so the collective roofline term stays negligible at any corpus
 size (quantified in EXPERIMENTS.md SSRoofline).
 
-Known limitation (jax 0.4.x), now *detected* instead of documented:
-wrapping the search step in an *outer* ``jax.jit`` miscompiles the
-engine's data-dependent verification ``while_loop`` under
-``shard_map(check_rep=False)`` — results silently drop candidates
-(reproduced against brute force down to mesh (4, 2), N=32, L=16;
-``check_rep=True`` is unavailable: 0.4.x has no replication rule for
-``while``).  ``make_distributed_search`` therefore runs
-``guards.preflight_shard_map`` by default (``jit="auto"``): the real
-search step is jitted on a tiny canary store on the *same mesh* and
-compared against host brute force — exact means the returned step is
-``jax.jit``-wrapped (jax >= 0.6 takes this path), a mismatch means the
-safe unjitted per-shard-compiled step is returned and a ``GuardWarning``
-fires once per process.  The verdict is cached per (mesh shape, axes,
-jax version), so the canary cost is paid once.
-``tests/test_distributed.py`` pins the detection itself: the auto path
-must be exact on the exact mesh/shape that miscompiles, and the raw
-jitted step must disagree with brute force iff preflight said so.
+``make_distributed_search`` returns the step under ``jax.jit``: one
+program per mesh, its verification ``while_loop`` included.
+``tests/test_distributed.py`` pins the jitted step against brute force
+on the (4, 2), N=256, L=128, k=3 shape that jax 0.4.x miscompiled.
 """
 
 from __future__ import annotations
@@ -94,16 +81,10 @@ from repro.search.pipeline import (
 Array = jax.Array
 
 
-def _axis_size(axis: str) -> Array:
-    if hasattr(lax, "axis_size"):                      # jax >= 0.6
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)                           # jax 0.4.x
-
-
 def _combined_axis_index(axes: Sequence[str]) -> Array:
     idx = lax.axis_index(axes[0])
     for a in axes[1:]:
-        idx = idx * _axis_size(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
@@ -284,10 +265,7 @@ def _build_step(
     with_guards: bool = False,
     with_sketch: bool = False,
 ):
-    """The raw (unjitted) shard_map search step — shared by
-    ``make_distributed_search`` and the preflight canary (which must
-    build the *real* step: the minimal while_loop repro does not trip
-    the 0.4.x miscompile, the engine's verification loop does).
+    """The shard_map search step that ``make_distributed_search`` jits.
 
     ``with_sketch`` extends the leaf contract with the quantised sketch
     store (search/index.py): ``sk_lo``/``sk_hi`` row-shard along N like
@@ -297,8 +275,7 @@ def _build_step(
     its own rows and the top-k merge semantics are untouched (dead
     candidates keep their finite cheap-tier bounds; see cascade.run_plan).
     ``False`` (the default) keeps the historical 7-leaf shape that the
-    preflight canary, the subprocess repro scripts, and every existing
-    caller pin."""
+    subprocess repro scripts and every existing caller pin."""
     axes = tuple(data_axes)
     if plan is None:
         plan = _default_distributed_plan(cfg, axes, global_budget)
@@ -387,11 +364,10 @@ def make_distributed_search(
     query_axis: str = "model",
     global_budget: bool = True,
     plan: VerificationPlan | None = None,
-    jit: bool | str = "auto",
     with_guards: bool = False,
     with_sketch: bool = False,
 ):
-    """Build a distributed search step for ``mesh``.
+    """Build the jitted distributed search step for ``mesh``.
 
     Returns ``step(series, labels, upper, lower, kim, kim_ok, queries)``
     mapping sharded index leaves + queries to ``(dists, idx, n_dtw)`` with
@@ -414,17 +390,6 @@ def make_distributed_search(
     plan already carries the composed global-budget/refine-limit
     compaction, so it is used as-is.
 
-    ``jit`` selects the degradation policy for the jax 0.4.x
-    ``jit(shard_map(while))`` miscompile (module docstring):
-
-      * ``"auto"`` (default): run ``guards.preflight_shard_map`` once per
-        (mesh shape, axes, jax version) — exact canary gets the
-        ``jax.jit``-wrapped step, a miscompiling one gets the safe
-        unjitted step plus a once-per-process ``GuardWarning``;
-      * ``True`` / ``False``: skip the canary and force the jitted /
-        unjitted step (``True`` on a known-bad jax serves wrong results
-        — it exists for the preflight test itself).
-
     ``with_guards`` appends the fleet-merged ``GuardReport`` *vector*
     (``GuardReport.from_vector`` restores the struct) as a fourth output:
     per-shard reports are psum/pmax-merged over the whole mesh inside the
@@ -432,26 +397,11 @@ def make_distributed_search(
     conservation (including the shard-dropout echo check on the top-k
     all_gather), accounting, and finite gates.
     """
-    step = _build_step(
+    return jax.jit(_build_step(
         mesh, cfg, data_axes=data_axes, query_axis=query_axis,
         global_budget=global_budget, plan=plan, with_guards=with_guards,
         with_sketch=with_sketch,
-    )
-    if jit is False:
-        return step
-    if jit is True:
-        return jax.jit(step)
-    safe = _guards.preflight_shard_map(mesh, tuple(data_axes), query_axis)
-    if safe:
-        return jax.jit(step)
-    _guards.warn_once(
-        "jit_shard_map_while",
-        "preflight: jit(shard_map) miscompiles the verification "
-        f"while_loop on this jax ({jax.__version__}) — candidates are "
-        "silently dropped; auto-selected the unjitted per-shard-compiled "
-        "search step (exact, modestly slower dispatch)",
-    )
-    return step
+    ))
 
 
 def shard_index(mesh: Mesh, index: DTWIndex, data_axes=("data",)) -> DTWIndex:

@@ -5,8 +5,8 @@ DESIGN — why this module exists
 
 The whole search stack sells one contract: results bit-equal to brute
 force.  PRs 1-5 *prove* that contract on clean inputs and a correct
-compiler — but the carried jax 0.4.x ``jit(shard_map(while))`` miscompile
-shows the contract can fail *silently* (candidates dropped with no
+compiler — but the jax 0.4.x ``jit(shard_map(while))`` miscompile
+showed the contract can fail *silently* (candidates dropped with no
 error), and nothing validated inputs: a single NaN in a stored series
 poisons envelopes, Kim features, and every admissible bound without any
 signal (a NaN bound compares ``False`` everywhere, so the cascade simply
@@ -72,12 +72,7 @@ DESIGN — degradation ladder
 
   0. **preflight** — before serving traffic, prove the compiled path on
      a canary: ``preflight_engine()`` (single-device jitted engine vs
-     brute force) and ``preflight_shard_map(mesh, ...)`` (the exact
-     ``jit(shard_map(while))`` shape that miscompiles on jax 0.4.x,
-     compared against host brute force).  ``make_distributed_search``
-     runs the latter by default and auto-selects the safe unjitted path
-     with a one-per-process warning — the detection that replaced the
-     docs-only workaround.
+     brute force).
   1. **in-trace containment** — finite gates replace poisoned bounds
      with -inf (degrade to verification) and poisoned DTW values with
      +inf, and count every gated value.  Exactness is preserved whenever
@@ -562,34 +557,11 @@ def fault_hook(name: str) -> Callable | None:
 # ---------------------------------------------------------------------------
 
 _PREFLIGHT_CACHE: dict = {}
-_WARN_COUNTS: dict[str, int] = {}
-
-
-def warn_once(key: str, message: str) -> bool:
-    """Emit a ``GuardWarning`` exactly once per process per key.
-
-    Returns ``True`` when the warning actually fired — the promoted
-    miscompile test asserts the once-per-process contract through
-    ``warn_count``.
-    """
-    n = _WARN_COUNTS.get(key, 0)
-    _WARN_COUNTS[key] = n + 1
-    if n == 0:
-        warnings.warn(message, GuardWarning, stacklevel=3)
-        return True
-    return False
-
-
-def warn_count(key: str) -> int:
-    """How many times ``warn_once(key, ...)`` was *requested* (the
-    warning itself fired at most once)."""
-    return _WARN_COUNTS.get(key, 0)
 
 
 def preflight_clear() -> None:
-    """Drop cached preflight verdicts and warning bookkeeping (tests)."""
+    """Drop cached preflight verdicts (tests)."""
     _PREFLIGHT_CACHE.clear()
-    _WARN_COUNTS.clear()
 
 
 def _canary_store(n: int, length: int, seed: int = 0):
@@ -626,86 +598,13 @@ def preflight_engine() -> bool:
     want, _ = brute_force(idx, queries, 4, k=2, use_pallas=False)
     ok = bool(np.allclose(np.asarray(got), np.asarray(want), rtol=1e-4))
     if not ok:
-        warn_once(
-            "preflight_engine",
+        # the verdict is cached below, so this fires once per process
+        warnings.warn(
             "preflight: jitted single-device engine does not match brute "
             "force on the canary store — keep runtime guards on and "
             "expect degradation reruns",
+            GuardWarning,
+            stacklevel=2,
         )
-    _PREFLIGHT_CACHE[key] = ok
-    return ok
-
-
-def preflight_shard_map(
-    mesh,
-    data_axes: tuple[str, ...] = ("data",),
-    query_axis: str = "model",
-) -> bool:
-    """Detect the ``jit(shard_map(engine while_loop))`` miscompile.
-
-    Runs the *real* distributed search step — the minimal while_loop
-    canary does NOT reproduce the jax 0.4.x bug; the engine's
-    data-dependent verification loop does, even at N=32, L=16 — jitted,
-    on the given mesh, against host-side brute force.  Whether a
-    dropped candidate actually changes the returned top-k is
-    data-dependent, so the canary sweeps several seeded stores (on the
-    affected jax versions roughly two in three trip) and reports safe
-    only if *every* one is exact.  Returns ``True`` when the jitted
-    path is exact (jax >= 0.6), ``False`` on the 0.4.x miscompile.
-    Cached per (mesh shape, axes, jax version), so a process pays the
-    ~seconds canary once; ``make_distributed_search`` consults this to
-    auto-select the safe unjitted path (replacing the docs-only
-    workaround).
-    """
-    axes = tuple(data_axes)
-    key = (
-        "shard_map_while",
-        tuple(sorted(mesh.shape.items())),
-        axes,
-        query_axis,
-        jax.__version__,
-    )
-    hit = _PREFLIGHT_CACHE.get(key)
-    if hit is not None:
-        return hit
-    from repro.search.cascade import CascadeConfig
-    from repro.search.distributed import _build_step, shard_index
-    from repro.search.engine import EngineConfig, brute_force
-    from repro.search.index import build_index
-
-    D = 1
-    for a in axes:
-        D *= mesh.shape[a]
-    Qsh = mesh.shape[query_axis]
-    n_local, L, w, k = 8, 16, 4, 2
-    Q = Qsh * 4
-    cfg = EngineConfig(
-        cascade=CascadeConfig(w=w, v=4, candidate_chunk=n_local,
-                              use_pallas=False),
-        verify_chunk=4, k=k,
-    )
-    step = None
-    ok = True
-    for seed in range(3):
-        rng = np.random.default_rng(seed)
-        series = rng.normal(size=(D * n_local, L)).astype(np.float32)
-        queries = rng.normal(size=(Q, L)).astype(np.float32)
-        idx = build_index(series, w)
-        sidx = shard_index(mesh, idx, axes)
-        if step is None:
-            step = jax.jit(_build_step(
-                mesh, cfg, data_axes=axes, query_axis=query_axis))
-        try:
-            got, _, _ = step(
-                sidx.series, sidx.labels, sidx.upper, sidx.lower,
-                sidx.kim, sidx.kim_ok, jnp.asarray(queries),
-            )
-            want, _ = brute_force(idx, queries, w, k=k, use_pallas=False)
-            ok = bool(np.allclose(np.asarray(got), np.asarray(want),
-                                  rtol=1e-4))
-        except Exception:   # a jit that *fails loudly* is also unsafe
-            ok = False
-        if not ok:
-            break
     _PREFLIGHT_CACHE[key] = ok
     return ok

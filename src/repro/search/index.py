@@ -215,10 +215,7 @@ def build_index(
     ``preflight`` runs ``guards.preflight_engine()`` — the single-device
     jitted-engine-vs-brute-force canary — before the store is returned,
     warning (once per process) if the compiled path is not exact on this
-    jax install.  The distributed analogue lives in
-    ``make_distributed_search`` (its preflight is on by default because
-    the jax 0.4.x ``jit(shard_map(while))`` miscompile is a known,
-    detectable failure).
+    jax install.
 
     ``calibrate`` (an ``EngineConfig`` or ``CascadeConfig``) runs store-
     level plan calibration at build time: a ``calibrate_sample``-series

@@ -8,9 +8,7 @@ property of the shardings attached to ``params``, not of this code.
 Serving checklist (applies equally to a search deployment — see
 search/guards.py): before a process takes traffic, run the preflight
 self-tests against the compiled paths it will serve from —
-``build_index(..., preflight=True)`` for the single-device engine,
-``preflight_shard_map(mesh, ...)`` (or simply
-``make_distributed_search(..., jit="auto")``) for the sharded step — and
+``build_index(..., preflight=True)`` for the single-device engine — and
 admit inputs through the hygiene boundary (``sanitize=`` on
 ``build_index`` / ``nn_search``) rather than trusting upstream data.  The
 runtime guards then stay default-on; a tripped guard degrades to the

@@ -191,30 +191,18 @@ print("OK", int(np.array(ndtw).sum()), "<=", int(np.array(ndtw0).sum()))
 """)
 
 
-def test_preflight_detects_jit_shard_map_miscompile():
-    """The promoted form of the old strict-xfail ``jit(shard_map(while))``
-    pin: ``preflight_shard_map`` must *agree with reality* — its verdict
-    has to match whether a raw ``jax.jit(step)`` of the pinned
-    miscompiling mesh/shape (4, 2), N=256, L=128, k=3 is exact — and
-    ``make_distributed_search(jit="auto")`` must serve exact results
-    either way, warning exactly once per process when it declines the
-    jit.  On jax 0.4.x this proves detection (verdict False, unjitted
-    path selected); on a fixed jax (>= 0.6, jax.shard_map + vma checks)
-    it passes with verdict True and no warning — the XPASS analogue,
-    with the auto path silently re-gaining the jit."""
+def test_jitted_step_exact_on_pinned_repro():
+    """The jitted distributed step equals brute force on the mesh/shape
+    that jax 0.4.x miscompiled — (4, 2), N=256, L=128, k=3 — where
+    ``jit(shard_map(while))`` silently dropped candidates.  The step is
+    always jitted now, so this pins the installed jax to the fix."""
     _run("""
-import warnings
 import numpy as np, jax, jax.numpy as jnp
 from repro.data import make_dataset
 from repro.search import (build_index, brute_force, EngineConfig, CascadeConfig,
-                          make_distributed_search, shard_index,
-                          preflight_shard_map, GuardWarning)
-from repro.search import guards as _g
+                          make_distributed_search, shard_index)
 from repro.launch.mesh import make_host_mesh
 mesh = make_host_mesh((4, 2), ("data", "model"))
-
-verdict = preflight_shard_map(mesh, data_axes=("data",), query_axis="model")
-
 ds = make_dataset(n_classes=4, n_train_per_class=64, n_test_per_class=1,
                   length=128, seed=3)
 idx = build_index(ds.x_train, 16, ds.y_train)   # N = 256, L = 128
@@ -222,33 +210,14 @@ cfg = EngineConfig(cascade=CascadeConfig(w=16, v=4, candidate_chunk=64,
                                          use_pallas=False), verify_chunk=8, k=3)
 sidx = shard_index(mesh, idx, ("data",))
 q = jnp.asarray(ds.x_test)
-bd, _ = brute_force(idx, ds.x_test, 16, k=3, use_pallas=False)
-
-# ground truth: is the raw jitted step exact on the pinned repro shape?
-raw = make_distributed_search(mesh, cfg, data_axes=("data",),
-                              query_axis="model", jit=False)
-dj, _, _ = jax.jit(raw)(sidx.series, sidx.labels, sidx.upper, sidx.lower,
-                        sidx.kim, sidx.kim_ok, q)
-jit_exact = bool(np.allclose(np.array(dj), np.array(bd), rtol=1e-4))
-assert verdict == jit_exact, (
-    f"preflight verdict {verdict} disagrees with reality {jit_exact}")
-
-# the auto path must be exact regardless of the verdict, and must warn
-# exactly once per process when it declines the jit
-with warnings.catch_warnings(record=True) as w:
-    warnings.simplefilter("always")
-    step = make_distributed_search(mesh, cfg, data_axes=("data",),
-                                   query_axis="model", jit="auto")
-    step2 = make_distributed_search(mesh, cfg, data_axes=("data",),
-                                    query_axis="model", jit="auto")
-gw = [x for x in w if issubclass(x.category, GuardWarning)]
-assert len(gw) == (0 if verdict else 1), gw
-assert _g.warn_count("jit_shard_map_while") == (0 if verdict else 2)
-d, _, _ = step(sidx.series, sidx.labels, sidx.upper, sidx.lower,
+bd, bi = brute_force(idx, ds.x_test, 16, k=3, use_pallas=False)
+step = make_distributed_search(mesh, cfg, data_axes=("data",),
+                               query_axis="model")
+d, i, _ = step(sidx.series, sidx.labels, sidx.upper, sidx.lower,
                sidx.kim, sidx.kim_ok, q)
-assert np.allclose(np.array(d), np.array(bd), rtol=1e-4), (
-    "auto path dropped candidates")
-print("OK verdict =", verdict, "| jax", jax.__version__)
+assert np.allclose(np.array(d), np.array(bd), rtol=1e-4), "jitted step != brute force"
+assert (np.sort(np.array(i), 1) == np.sort(np.array(bi), 1)).all()
+print("OK | jax", jax.__version__)
 """)
 
 

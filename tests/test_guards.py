@@ -439,8 +439,7 @@ cfg = EngineConfig(cascade=CascadeConfig(w=8, v=4, candidate_chunk=16,
                                          use_pallas=False), verify_chunk=4, k=2)
 sidx = shard_index(mesh, idx, ("data",))
 step = make_distributed_search(mesh, cfg, data_axes=("data",),
-                               query_axis="model", jit=False,
-                               with_guards=True)
+                               query_axis="model", with_guards=True)
 bd, _ = brute_force(idx, q, 8, k=2, use_pallas=False)
 """
 

@@ -200,11 +200,39 @@ def test_dtw_band_f64_interpret(rng):
 
 
 def test_long_series_fallback(rng):
-    """Series beyond the kernel VMEM budget route to the jnp reference."""
+    """Series beyond the kernel VMEM budget route to the jnp reference,
+    with a ``KernelFallbackWarning`` once per kernel and shape."""
+    import warnings
+
     L = 70000   # > envelope kernel budget
-    b = jnp.array(rng.normal(size=(1, L)).astype(np.float32))
-    u, lo = ops.envelope_op(b, 10)
-    assert u.shape == (1, L) and lo.shape == (1, L)
+    stores = [jnp.array(rng.normal(size=(n, L)).astype(np.float32))
+              for n in (1, 1, 2)]
+    # the "default" action shows a message once per call site until the
+    # filters change, so nothing inside the block may touch them
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("default")
+        outs = [ops.envelope_op(b, 10) for b in stores]
+    for b, (u, lo) in zip(stores, outs):
+        assert u.shape == b.shape and lo.shape == b.shape
+        np.testing.assert_array_equal(np.array(u),
+                                      np.array(ref.envelope_ref(b, 10)[0]))
+    fell = [str(w.message) for w in caught
+            if issubclass(w.category, ops.KernelFallbackWarning)]
+    assert len(fell) == 2, fell
+    assert "(1, 70000)" in fell[0] and "(2, 70000)" in fell[1]
+
+
+def test_interpret_only_on_cpu_compiled_only_on_tpu(monkeypatch):
+    """Kernels interpret on cpu, compile on tpu, and refuse any other
+    backend rather than pick a silent third path."""
+    import jax
+
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops._interpret() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()
 
 
 # ---------------------------------------------------------------------------

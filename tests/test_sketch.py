@@ -181,6 +181,15 @@ def test_sketch_kernel_tiling_is_result_invariant(rng):
                 interpret=True)),
             base, rtol=1e-6,
         )
+    # past one 128-row query tile: a zero-padded ragged last query tile
+    qbar = sketch_query_means(jnp.asarray(_walks(rng, 300, 64)), s)
+    np.testing.assert_allclose(
+        np.array(sketch_bound_pallas(
+            qbar / scale, index.sk_lo, index.sk_hi, wseg, interpret=True)),
+        np.array(ref.sketch_bound_ref(
+            qbar, index.sk_lo, index.sk_hi, index.sk_scale, seg)),
+        rtol=1e-5, atol=1e-6,
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -175,7 +175,9 @@ def test_stream_geometry_fits_budget():
         assert geom is not None, (L, w)
         tile, R = geom
         Wb = -(-(2 * w + 1) // 128) * 128
-        Wwin = -(-(R + Wb) // 128) * 128
+        # the kernel's window: R + Wb lanes from a 128-aligned origin,
+        # read through (Wb + 128)-lane aligned loads
+        Wwin = -(-(R + 127) // 128) * 128 + Wb
         per_row = (4 * Wwin + 8 * Wb) * 4
         assert tile * per_row <= budget
         assert tile % 8 == 0 and tile >= 8
