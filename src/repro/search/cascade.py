@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.kernels import ref as kref
 from repro.kernels.ops import (
     dtw_band_op,
@@ -411,8 +412,14 @@ def run_plan(
     only comparisons).  The checks are pure jnp and never raise — the
     outcome lands in ``CascadeResult.guard``.  On clean finite data
     every gate is the identity, so guarded results are bit-equal to
-    unguarded ones (property-tested; overhead priced by the
-    ``guard_overhead_*`` bench rows).
+    unguarded ones (property-tested).  Their cost on the chip is in the
+    benchmark's traced runs (``cascade_host_ms_per_request.batch`` holds
+    this executor's host time; PERF.md and the ledger).
+
+    Each all-pairs tier runs in the span ``repro.cascade.<tier name>``,
+    the compaction in ``repro.cascade.compact``, the pairwise chunk loop
+    in ``repro.cascade.pairwise`` and the seed verification in
+    ``repro.cascade.seeds`` (``repro.obs``).
 
     ``collect_stats`` makes the executor *instrumented*: it snapshots the
     running bound after every tier and, once the seeds fix the threshold
@@ -458,16 +465,17 @@ def run_plan(
     for tier in plan.all_pairs_tiers:
         masked = store_live is not None and _accepts_live(tier.fn)
         ap_masked.append(masked)
-        if masked:
-            t = tier.fn(q, index, cfg, live=store_live)
-        else:
-            t = tier.fn(q, index, cfg)
-        if hook_tier is not None:
-            t = hook_tier(t, tier.name)
-        if gon and g.finite_gates:
-            t, gated = _guards.finite_gate_bounds(t)
-            nf_bounds = nf_bounds + gated
-        lb01 = t if lb01 is None else jnp.maximum(lb01, t)
+        with obs.span("cascade." + tier.name):
+            if masked:
+                t = tier.fn(q, index, cfg, live=store_live)
+            else:
+                t = tier.fn(q, index, cfg)
+            if hook_tier is not None:
+                t = hook_tier(t, tier.name)
+            if gon and g.finite_gates:
+                t, gated = _guards.finite_gate_bounds(t)
+                nf_bounds = nf_bounds + gated
+            lb01 = t if lb01 is None else jnp.maximum(lb01, t)
         if collect_stats:
             ap_snaps.append(lb01)
     if lb01 is None:
@@ -476,94 +484,96 @@ def run_plan(
     pairwise_tiers = plan.pairwise_tiers
     if pairwise_tiers:
         # ---- compaction: gather the B most promising survivors ---------
-        comp = plan.compaction
-        B = comp.budget if comp.budget is not None else cfg.budget(n, k)
-        B = max(1, min(n, B))
-        sel_key = (
-            lb01 if exclude is None
-            else lb01.at[qarange, exclude].set(_INF)
-        )
-        if comp.limit_fn is None:
-            W, limit = B, None
-        else:
-            # static packed width leaves headroom above the uniform budget
-            # so the policy can over-allocate to a skewed shard; the
-            # per-query limits are traced values, the shapes are not
-            W = max(1, min(n, comp.width_scale * B))
-            limit = jnp.clip(
-                comp.limit_fn(sel_key, B, k), min(k, W), W
-            ).astype(jnp.int32)
-        _, cand = lax.top_k(-sel_key, W)             # ascending cheap bound
-        hook_cand = _guards.fault_hook("compaction_cand")
-        if hook_cand is not None:
-            cand = hook_cand(cand)
-        if gon and g.conservation:
-            cc, cv = _guards.conservation_check(cand, n)
-            c_checked, c_viol = c_checked + cc, c_viol + cv
+        with obs.span("cascade.compact"):
+            comp = plan.compaction
+            B = comp.budget if comp.budget is not None else cfg.budget(n, k)
+            B = max(1, min(n, B))
+            sel_key = (
+                lb01 if exclude is None
+                else lb01.at[qarange, exclude].set(_INF)
+            )
+            if comp.limit_fn is None:
+                W, limit = B, None
+            else:
+                # static packed width leaves headroom above the uniform
+                # budget so the policy can over-allocate to a skewed shard;
+                # the per-query limits are traced values, the shapes are not
+                W = max(1, min(n, comp.width_scale * B))
+                limit = jnp.clip(
+                    comp.limit_fn(sel_key, B, k), min(k, W), W
+                ).astype(jnp.int32)
+            _, cand = lax.top_k(-sel_key, W)         # ascending cheap bound
+            hook_cand = _guards.fault_hook("compaction_cand")
+            if hook_cand is not None:
+                cand = hook_cand(cand)
+            if gon and g.conservation:
+                cc, cv = _guards.conservation_check(cand, n)
+                c_checked, c_viol = c_checked + cc, c_viol + cv
 
         # ---- pairwise tiers on the packed survivor batches -------------
-        chunk = min(cfg.candidate_chunk, W)
-        cols = []
-        pw_snaps = [[] for _ in pairwise_tiers]   # per-tier running max
-        plive = None                   # live pair count under any masking
-        for s in range(0, W, chunk):
-            e = min(s + chunk, W)
-            cidx = cand[:, s:e].reshape(-1)          # (Q * bc,)
-            qf = jnp.repeat(q, e - s, axis=0)
-            crows = index.series[cidx]
-            urows = index.upper[cidx]
-            lrows = index.lower[cidx]
-            hook_rows = _guards.fault_hook("packed_rows")
-            if hook_rows is not None:
-                crows, urows, lrows = hook_rows(crows, urows, lrows)
-            # per-slot liveness from this query's refine allocation: the
-            # packed layout keeps one query's slots contiguous, so light
-            # queries yield whole dead pair tiles and the tier kernels
-            # skip them outright (dead slots come back -inf — the
-            # identity of the scatter-max below, so unrefined slots keep
-            # their cheap tier-0/1 bound).  The store-level mask ANDs in
-            # per *candidate*: a dead-store slot is dead in every
-            # query's allocation.
-            slot = jnp.arange(s, e)[None, :]
-            live2d = None if limit is None else (slot < limit[:, None])
-            if store_live is not None:
-                sl = store_live[cidx].reshape(Q, e - s)
-                live2d = sl if live2d is None else (live2d & sl)
-            live = None if live2d is None else live2d.reshape(-1)
-            if live2d is not None:
-                c = jnp.sum(live2d).astype(jnp.float32)
-                plive = c if plive is None else plive + c
-            pe = None
-            for ti, tier in enumerate(pairwise_tiers):
-                if live is not None and _accepts_live(tier.fn):
-                    t = tier.fn(qf, crows, urows, lrows, cfg, live=live)
-                else:   # no limit, or a pre-liveness custom tier
-                    t = tier.fn(qf, crows, urows, lrows, cfg)
-                if hook_tier is not None:
-                    t = hook_tier(t, tier.name)
-                if gon and g.finite_gates:
-                    t, gated = _guards.finite_gate_bounds(t)
-                    nf_bounds = nf_bounds + gated
-                pe = t if pe is None else jnp.maximum(pe, t)
-                if collect_stats:
-                    # running pairwise max after this tier, dead slots at
-                    # the -inf scatter-max identity (the belt mask keeps
-                    # pre-liveness custom tiers honest here too)
-                    snap = pe.reshape(Q, e - s)
-                    if live2d is not None:
-                        snap = jnp.where(live2d, snap, -_INF)
-                    pw_snaps[ti].append(snap)
-            block = pe.reshape(Q, e - s)
-            if live2d is not None:
-                # belt for tiers without ``live`` support: the mask is
-                # idempotent over the kernel's own -inf dead slots
-                block = jnp.where(live2d, block, -_INF)
-            cols.append(block)
-        enh = jnp.concatenate(cols, axis=1) if len(cols) > 1 else cols[0]
-        lb = lb01.at[qarange[:, None], cand].max(enh)
-        if gon and g.conservation:
-            mc, mv = _guards.scatter_monotone_check(lb01, lb)
-            c_checked, c_viol = c_checked + mc, c_viol + mv
+        with obs.span("cascade.pairwise"):
+            chunk = min(cfg.candidate_chunk, W)
+            cols = []
+            pw_snaps = [[] for _ in pairwise_tiers]   # per-tier running max
+            plive = None                   # live pair count under any masking
+            for s in range(0, W, chunk):
+                e = min(s + chunk, W)
+                cidx = cand[:, s:e].reshape(-1)          # (Q * bc,)
+                qf = jnp.repeat(q, e - s, axis=0)
+                crows = index.series[cidx]
+                urows = index.upper[cidx]
+                lrows = index.lower[cidx]
+                hook_rows = _guards.fault_hook("packed_rows")
+                if hook_rows is not None:
+                    crows, urows, lrows = hook_rows(crows, urows, lrows)
+                # per-slot liveness from this query's refine allocation: the
+                # packed layout keeps one query's slots contiguous, so light
+                # queries yield whole dead pair tiles and the tier kernels
+                # skip them outright (dead slots come back -inf — the
+                # identity of the scatter-max below, so unrefined slots keep
+                # their cheap tier-0/1 bound).  The store-level mask ANDs in
+                # per *candidate*: a dead-store slot is dead in every
+                # query's allocation.
+                slot = jnp.arange(s, e)[None, :]
+                live2d = None if limit is None else (slot < limit[:, None])
+                if store_live is not None:
+                    sl = store_live[cidx].reshape(Q, e - s)
+                    live2d = sl if live2d is None else (live2d & sl)
+                live = None if live2d is None else live2d.reshape(-1)
+                if live2d is not None:
+                    c = jnp.sum(live2d).astype(jnp.float32)
+                    plive = c if plive is None else plive + c
+                pe = None
+                for ti, tier in enumerate(pairwise_tiers):
+                    if live is not None and _accepts_live(tier.fn):
+                        t = tier.fn(qf, crows, urows, lrows, cfg, live=live)
+                    else:   # no limit, or a pre-liveness custom tier
+                        t = tier.fn(qf, crows, urows, lrows, cfg)
+                    if hook_tier is not None:
+                        t = hook_tier(t, tier.name)
+                    if gon and g.finite_gates:
+                        t, gated = _guards.finite_gate_bounds(t)
+                        nf_bounds = nf_bounds + gated
+                    pe = t if pe is None else jnp.maximum(pe, t)
+                    if collect_stats:
+                        # running pairwise max after this tier, dead slots at
+                        # the -inf scatter-max identity (the belt mask keeps
+                        # pre-liveness custom tiers honest here too)
+                        snap = pe.reshape(Q, e - s)
+                        if live2d is not None:
+                            snap = jnp.where(live2d, snap, -_INF)
+                        pw_snaps[ti].append(snap)
+                block = pe.reshape(Q, e - s)
+                if live2d is not None:
+                    # belt for tiers without ``live`` support: the mask is
+                    # idempotent over the kernel's own -inf dead slots
+                    block = jnp.where(live2d, block, -_INF)
+                cols.append(block)
+            enh = jnp.concatenate(cols, axis=1) if len(cols) > 1 else cols[0]
+            lb = lb01.at[qarange[:, None], cand].max(enh)
+            if gon and g.conservation:
+                mc, mv = _guards.scatter_monotone_check(lb01, lb)
+                c_checked, c_viol = c_checked + mc, c_viol + mv
     else:
         lb = lb01
 
@@ -573,44 +583,46 @@ def run_plan(
     # ascending-bound loop would perform anyway — the threshold tier costs
     # no extra DTW, it only moves those verifications before the loop so
     # tau = k-th seed distance can warm-start pruning and cutoffs.
-    seed_sel = lb if exclude is None else lb.at[qarange, exclude].set(_INF)
-    _, seed_idx = lax.top_k(-seed_sel, k)            # (Q, k)
-    qs = jnp.repeat(q, k, axis=0)                    # (Q*k, L)
-    cs = index.series[seed_idx.reshape(-1)]
-    # seeds are the tightest-bound pairs — almost all live, so the
-    # per-round tile policy keeps full tiles here; an explicit plan
-    # verify_tile_p still overrides (pipeline.py) when the dispatch
-    # understands it (a custom dtw_fn on the old (a, b, w) contract gets
-    # the plain call — tile size is packing geometry, never semantics)
-    if plan.verify_tile_p is not None and _accepts_kw(dtw_fn, "tile_p"):
-        seed_d = dtw_fn(qs, cs, cfg.w, tile_p=plan.verify_tile_p)
-    else:
-        seed_d = dtw_fn(qs, cs, cfg.w)
-    seed_d = seed_d.reshape(Q, k)
-    if gon and g.finite_gates:
-        # a NaN seed DTW would poison tau and the engine's warm start:
-        # gate it to +inf (unverifiable) and count the incident
-        seed_d, gated = _guards.finite_gate_dtw(seed_d)
-        nf_dtw = nf_dtw + gated
-    if gon and g.admissibility:
-        # the seeds *are* the sampled survivor pairs — their bound (the
-        # running max before the exact value lands) must not exceed
-        # their verified DTW; the comparison reuses values that already
-        # exist, so the spot-check costs no extra DTW
-        pre = jnp.take_along_axis(lb, seed_idx, axis=1)
-        ac, av, ag = _guards.admissibility_check(pre, seed_d, g.rtol, g.atol)
-        a_checked, a_viol = a_checked + ac, a_viol + av
-        a_gap = jnp.maximum(a_gap, ag)
-    # seed pairs are exactly verified: their distance is the perfect bound
-    if gon and g.finite_gates:
-        # a gated (+inf) seed must not poison the bound matrix — +inf
-        # there means "never verify", the exact failure the gates exist
-        # to prevent; the engine re-opens such seeds for verification
-        lb = lb.at[qarange[:, None], seed_idx].max(
-            jnp.where(jnp.isfinite(seed_d), seed_d, -_INF)
-        )
-    else:
-        lb = lb.at[qarange[:, None], seed_idx].max(seed_d)
+    with obs.span("cascade.seeds"):
+        seed_sel = lb if exclude is None else lb.at[qarange, exclude].set(_INF)
+        _, seed_idx = lax.top_k(-seed_sel, k)            # (Q, k)
+        qs = jnp.repeat(q, k, axis=0)                    # (Q*k, L)
+        cs = index.series[seed_idx.reshape(-1)]
+        # seeds are the tightest-bound pairs — almost all live, so the
+        # per-round tile policy keeps full tiles here; an explicit plan
+        # verify_tile_p still overrides (pipeline.py) when the dispatch
+        # understands it (a custom dtw_fn on the old (a, b, w) contract gets
+        # the plain call — tile size is packing geometry, never semantics)
+        if plan.verify_tile_p is not None and _accepts_kw(dtw_fn, "tile_p"):
+            seed_d = dtw_fn(qs, cs, cfg.w, tile_p=plan.verify_tile_p)
+        else:
+            seed_d = dtw_fn(qs, cs, cfg.w)
+        seed_d = seed_d.reshape(Q, k)
+        if gon and g.finite_gates:
+            # a NaN seed DTW would poison tau and the engine's warm start:
+            # gate it to +inf (unverifiable) and count the incident
+            seed_d, gated = _guards.finite_gate_dtw(seed_d)
+            nf_dtw = nf_dtw + gated
+        if gon and g.admissibility:
+            # the seeds *are* the sampled survivor pairs — their bound (the
+            # running max before the exact value lands) must not exceed
+            # their verified DTW; the comparison reuses values that already
+            # exist, so the spot-check costs no extra DTW
+            pre = jnp.take_along_axis(lb, seed_idx, axis=1)
+            ac, av, ag = _guards.admissibility_check(pre, seed_d, g.rtol,
+                                                     g.atol)
+            a_checked, a_viol = a_checked + ac, a_viol + av
+            a_gap = jnp.maximum(a_gap, ag)
+        # seed pairs are exactly verified: their distance is the perfect bound
+        if gon and g.finite_gates:
+            # a gated (+inf) seed must not poison the bound matrix — +inf
+            # there means "never verify", the exact failure the gates exist
+            # to prevent; the engine re-opens such seeds for verification
+            lb = lb.at[qarange[:, None], seed_idx].max(
+                jnp.where(jnp.isfinite(seed_d), seed_d, -_INF)
+            )
+        else:
+            lb = lb.at[qarange[:, None], seed_idx].max(seed_d)
 
     stats = None
     if collect_stats:

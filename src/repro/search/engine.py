@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import obs
 from repro.kernels.ops import dtw_band_op
 from repro.kernels.ref import dtw_band_ref
 from repro.kernels.tiling import sched_pair_tile, unpermute_pairs
@@ -125,8 +126,9 @@ class EngineConfig:
       guards: exactness-guard configuration (search/guards.py).  ``None``
         means the *default-on* ``GuardConfig()`` — admissibility spot
         checks, conservation, accounting and finite gates all run (their
-        overhead is priced and CI-bounded; see the ``guard_overhead_*``
-        bench rows).  Pass ``GuardConfig(enabled=False)`` to opt out;
+        host sync is measured on the chip by the benchmark's
+        ``guard_sync_ms_per_request.online``; see PERF.md and the
+        ledger).  Pass ``GuardConfig(enabled=False)`` to opt out;
         ``REPRO_FORCE_GUARDS=1`` in the environment overrides everything
         on.
     """
@@ -299,135 +301,150 @@ def nn_search(
     the rest of the batch plus every later search against this store and
     config runs the committed plan.  Neighbours are bit-equal to the
     base plan's by construction; only bound work changes.
+
+    Each call is the span ``repro.nn_search``, its steps spans inside it
+    (``repro.obs``; README, Observability).
     """
-    q = jnp.asarray(queries, jnp.float32)
-    hyg = None
-    if not isinstance(q, jax.core.Tracer):
-        q, hyg = _g.validate_series(q, name="query", sanitize=sanitize)
-    Q = q.shape[0]
-    N = index.n
-    k = min(cfg.k, N)
-    cascade = cfg.cascade
-    if plan is None:
-        # dense engines bound every pair with the all-pairs tier list; a
-        # staged default would smuggle pairwise tiers into a path that has
-        # no compaction to feed them (compute_bounds rejects that loudly)
-        plan = default_plan(cascade) if cascade.staged \
-            else dense_plan(cascade)
-    concrete = _all_concrete(q, index, exclude)
-    if with_stats and not (cascade.staged and concrete):
-        raise ValueError(
-            "with_stats is a host-side report over the staged tier "
-            "pipeline: it needs cascade.staged=True and concrete inputs"
-        )
-
-    pcfg = cfg.planner if cfg.planner is not None else PlannerConfig()
-    decision = None
-    stats = None
-    if cfg.auto_plan and cascade.staged and concrete and Q > 0:
-        decision = _planner.lookup_plan(index, cascade, k, plan, pcfg)
-        if decision is not None:
-            # committed: the whole batch runs the optimised plan
-            res, _, guard = _search(index, q, cfg, plan=decision.plan,
-                                    exclude=exclude)
-            stats = decision.stats
-        else:
-            # calibrate: a strided query block runs the full base plan
-            # (its bound pass doubles as the measurement), the rest of
-            # the batch commits.  The stride keeps class-ordered batches
-            # honest — a contiguous prefix can miss whole classes and
-            # mis-price every tier (planner.calibration_sample).
-            pick = _planner.calibration_sample(Q, pcfg.calibrate_block)
-            rest = np.setdiff1d(np.arange(Q), pick)
-            qa = q[pick]
-            ex_a = None if exclude is None else exclude[pick]
-            cascade_a = _resolve_cascade(qa, index, cascade, k, ex_a, plan)
-            res_a, stats, guard = _search(index, qa, cfg, plan=plan,
-                                          exclude=ex_a, cascade=cascade_a,
-                                          collect_stats=True)
-            decision = _planner.optimise_plan(
-                plan, stats, n=N, k=k,
-                base_budget=_planner.base_budget_for(
-                    index, cascade_a, k, plan),
-                pcfg=pcfg,
+    with obs.span("nn_search"):
+        q = jnp.asarray(queries, jnp.float32)
+        hyg = None
+        if not isinstance(q, jax.core.Tracer):
+            with obs.span("nn_search.hygiene"):
+                q, hyg = _g.validate_series(q, name="query", sanitize=sanitize)
+        Q = q.shape[0]
+        N = index.n
+        k = min(cfg.k, N)
+        cascade = cfg.cascade
+        if plan is None:
+            # dense engines bound every pair with the all-pairs tier list; a
+            # staged default would smuggle pairwise tiers into a path that has
+            # no compaction to feed them (compute_bounds rejects that loudly)
+            plan = default_plan(cascade) if cascade.staged \
+                else dense_plan(cascade)
+        concrete = _all_concrete(q, index, exclude)
+        if with_stats and not (cascade.staged and concrete):
+            raise ValueError(
+                "with_stats is a host-side report over the staged tier "
+                "pipeline: it needs cascade.staged=True and concrete inputs"
             )
-            _planner.commit_plan(index, cascade, k, plan, decision, pcfg)
-            if rest.size:
-                ex_b = None if exclude is None else exclude[rest]
-                res_b, _, guard_b = _search(index, q[rest], cfg,
-                                            plan=decision.plan,
-                                            exclude=ex_b)
-                if guard is not None and guard_b is not None:
-                    guard = guard.merge(guard_b)
-                inv = jnp.asarray(np.argsort(np.concatenate([pick, rest])))
-                res = SearchResult(
-                    dists=jnp.concatenate([res_a.dists, res_b.dists])[inv],
-                    idx=jnp.concatenate([res_a.idx, res_b.idx])[inv],
-                    n_dtw=jnp.concatenate([res_a.n_dtw, res_b.n_dtw])[inv],
-                    lb=jnp.concatenate([res_a.lb, res_b.lb])[inv],
-                )
+
+        pcfg = cfg.planner if cfg.planner is not None else PlannerConfig()
+        decision = None
+        stats = None
+        if cfg.auto_plan and cascade.staged and concrete and Q > 0:
+            with obs.span("nn_search.plan"):
+                decision = _planner.lookup_plan(index, cascade, k, plan, pcfg)
+            if decision is not None:
+                # committed: the whole batch runs the optimised plan
+                res, _, guard = _search(index, q, cfg, plan=decision.plan,
+                                        exclude=exclude)
+                stats = decision.stats
             else:
-                res = res_a
-        committed = decision.plan
-    else:
-        res, stats, guard = _search(index, q, cfg, plan=plan,
-                                    exclude=exclude,
-                                    collect_stats=with_stats)
-        committed = plan
+                # calibrate: a strided query block runs the full base plan
+                # (its bound pass doubles as the measurement), the rest of
+                # the batch commits.  The stride keeps class-ordered batches
+                # honest — a contiguous prefix can miss whole classes and
+                # mis-price every tier (planner.calibration_sample).
+                with obs.span("nn_search.plan"):
+                    pick = _planner.calibration_sample(Q, pcfg.calibrate_block)
+                    rest = np.setdiff1d(np.arange(Q), pick)
+                    qa = q[pick]
+                    ex_a = None if exclude is None else exclude[pick]
+                    cascade_a = _resolve_cascade(qa, index, cascade, k, ex_a,
+                                                 plan)
+                res_a, stats, guard = _search(index, qa, cfg, plan=plan,
+                                              exclude=ex_a, cascade=cascade_a,
+                                              collect_stats=True)
+                with obs.span("nn_search.plan"):
+                    decision = _planner.optimise_plan(
+                        plan, stats, n=N, k=k,
+                        base_budget=_planner.base_budget_for(
+                            index, cascade_a, k, plan),
+                        pcfg=pcfg,
+                    )
+                    _planner.commit_plan(index, cascade, k, plan, decision,
+                                         pcfg)
+                if rest.size:
+                    ex_b = None if exclude is None else exclude[rest]
+                    res_b, _, guard_b = _search(index, q[rest], cfg,
+                                                plan=decision.plan,
+                                                exclude=ex_b)
+                    if guard is not None and guard_b is not None:
+                        with obs.span("nn_search.guards"):
+                            guard = guard.merge(guard_b)
+                    inv = jnp.asarray(np.argsort(np.concatenate([pick, rest])))
+                    res = SearchResult(
+                        dists=jnp.concatenate([res_a.dists, res_b.dists])[inv],
+                        idx=jnp.concatenate([res_a.idx, res_b.idx])[inv],
+                        n_dtw=jnp.concatenate([res_a.n_dtw, res_b.n_dtw])[inv],
+                        lb=jnp.concatenate([res_a.lb, res_b.lb])[inv],
+                    )
+                else:
+                    res = res_a
+            committed = decision.plan
+        else:
+            res, stats, guard = _search(index, q, cfg, plan=plan,
+                                        exclude=exclude,
+                                        collect_stats=with_stats)
+            committed = plan
 
-    # ---- degradation ladder layer 2 (search/guards.py) -----------------
-    # a tripped admissibility / conservation / accounting / NaN-DTW guard
-    # means *neither the bounds nor the compiled verification path* can
-    # be trusted for this batch — pruning with a lying bound silently
-    # loses neighbours, and re-running the same cascade would consult the
-    # same lie.  The only sound serve is full verification: reference
-    # brute force (jnp kernels, no bound pruning, no Pallas dispatch),
-    # with the incident surfaced.  Host-side only — tripped() syncs.
-    gcfg = _g.resolve_guards(cfg.guards)
-    if hyg is not None and hyg.any() and guard is not None:
-        guard = guard.merge(_g.hygiene_to_report(hyg))
-    degraded = False
-    if (
-        guard is not None and gcfg.enabled and gcfg.degrade and concrete
-        and guard.tripped()
-    ):
-        trip = ", ".join(guard.tripped())
-        warnings.warn(
-            f"exactness guards tripped ({trip}): serving this query "
-            "batch via reference brute force (jnp kernels, bounds "
-            "untrusted); see SearchStats.guards",
-            _g.GuardWarning,
-            stacklevel=2,
-        )
-        bf_d, bf_i = brute_force(index, q, cascade.w, k=k, exclude=exclude,
-                                 use_pallas=False)
-        res = SearchResult(
-            dists=bf_d, idx=bf_i,
-            n_dtw=jnp.full((Q,), N, jnp.int32),
-            lb=res.lb,   # diagnostics only — flagged untrusted via degraded
-        )
-        guard = dataclasses.replace(guard, degraded=guard.degraded + 1.0)
-        degraded = True
+        # ---- degradation ladder layer 2 (search/guards.py) -----------------
+        # a tripped admissibility / conservation / accounting / NaN-DTW guard
+        # means *neither the bounds nor the compiled verification path* can
+        # be trusted for this batch — pruning with a lying bound silently
+        # loses neighbours, and re-running the same cascade would consult the
+        # same lie.  The only sound serve is full verification: reference
+        # brute force (jnp kernels, no bound pruning, no Pallas dispatch),
+        # with the incident surfaced.  Host-side only — tripped() syncs.
+        with obs.span("nn_search.guards"):
+            gcfg = _g.resolve_guards(cfg.guards)
+            if hyg is not None and hyg.any() and guard is not None:
+                guard = guard.merge(_g.hygiene_to_report(hyg))
+            trip = (
+                ", ".join(guard.tripped())
+                if guard is not None and gcfg.enabled and gcfg.degrade
+                and concrete else ""
+            )
+        degraded = False
+        if trip:
+            warnings.warn(
+                f"exactness guards tripped ({trip}): serving this query "
+                "batch via reference brute force (jnp kernels, bounds "
+                "untrusted); see SearchStats.guards",
+                _g.GuardWarning,
+                stacklevel=2,
+            )
+            with obs.span("nn_search.degrade"):
+                bf_d, bf_i = brute_force(index, q, cascade.w, k=k,
+                                         exclude=exclude, use_pallas=False)
+                res = SearchResult(
+                    dists=bf_d, idx=bf_i,
+                    n_dtw=jnp.full((Q,), N, jnp.int32),
+                    lb=res.lb,   # diagnostics only — untrusted via degraded
+                )
+                guard = dataclasses.replace(guard,
+                                            degraded=guard.degraded + 1.0)
+            degraded = True
 
-    if not with_stats:
-        if with_guards:
-            return res, (guard if guard is not None
-                         else _g.GuardReport.zeros())
-        return res
-    report = SearchStats(
-        tiers=stats,
-        plan_tiers=tuple(t.name for t in committed.tiers),
-        schedule=committed.schedule,
-        dropped=decision.dropped if decision is not None else (),
-        budget=decision.budget if decision is not None else None,
-        limit=decision.limit if decision is not None else None,
-        calibrated=decision is not None,
-        n_dtw=res.n_dtw,
-        n=N,
-        guards=guard,
-        degraded=degraded,
-    )
-    return res, report
+        if not with_stats:
+            if with_guards:
+                return res, (guard if guard is not None
+                             else _g.GuardReport.zeros())
+            return res
+        report = SearchStats(
+            tiers=stats,
+            plan_tiers=tuple(t.name for t in committed.tiers),
+            schedule=committed.schedule,
+            dropped=decision.dropped if decision is not None else (),
+            budget=decision.budget if decision is not None else None,
+            limit=decision.limit if decision is not None else None,
+            calibrated=decision is not None,
+            n_dtw=res.n_dtw,
+            n=N,
+            guards=guard,
+            degraded=degraded,
+        )
+        return res, report
 
 
 def _search(
@@ -454,50 +471,21 @@ def _search(
     N = index.n
     k = min(cfg.k, N)
     M = min(cfg.verify_chunk, N)
-    if cascade is None:
-        cascade = _resolve_cascade(q, index, cfg.cascade, k, exclude, plan)
-    w = cascade.w
-    dtw_fn = dtw_band_op if cascade.use_pallas else dtw_band_ref
-    qarange = jnp.arange(Q)
-
     g = _g.resolve_guards(cfg.guards)
     gon = g.enabled
-
-    tier_stats = None
-    guard0 = None
-    if cascade.staged:
-        cres = run_plan(
-            q, index, cascade, plan, k=k, dtw_fn=dtw_fn, exclude=exclude,
-            collect_stats=collect_stats, guards=g,
-        )
-        tier_stats = cres.stats
-        guard0 = cres.guard
-        lb = cres.lb
-        # seeds are already verified: warm-start the top-k with them and
-        # drop them from the unverified ordering
-        sel = jnp.argsort(cres.seed_d, axis=1)
-        best_d0 = jnp.take_along_axis(cres.seed_d, sel, axis=1)
-        best_i0 = jnp.take_along_axis(cres.seed_idx, sel, axis=1)
-        n_dtw0 = jnp.full((Q,), k, jnp.int32)
-        if gon and g.finite_gates:
-            # a gated (+inf) seed was never really verified: leave its
-            # bound in the ordering so the loop verifies the candidate
-            # instead of losing it behind the seed mask
-            cur = jnp.take_along_axis(lb, cres.seed_idx, axis=1)
-            lb_order = lb.at[qarange[:, None], cres.seed_idx].set(
-                jnp.where(jnp.isfinite(cres.seed_d), _INF, cur)
+    with obs.span("engine.bounds"):
+        if cascade is None:
+            cascade = _resolve_cascade(q, index, cfg.cascade, k, exclude,
+                                       plan)
+        dtw_fn = dtw_band_op if cascade.use_pallas else dtw_band_ref
+        if cascade.staged:
+            cres = run_plan(
+                q, index, cascade, plan, k=k, dtw_fn=dtw_fn,
+                exclude=exclude, collect_stats=collect_stats, guards=g,
             )
         else:
-            lb_order = lb.at[qarange[:, None], cres.seed_idx].set(_INF)
-    else:
-        lb = compute_bounds(q, index, cascade, k=k, plan=plan)
-        best_d0 = jnp.full((Q, k), _INF, jnp.float32)
-        best_i0 = jnp.full((Q, k), -1, jnp.int32)
-        n_dtw0 = jnp.zeros((Q,), jnp.int32)
-        lb_order = lb
-    if exclude is not None:
-        lb = lb.at[qarange, exclude].set(_INF)
-        lb_order = lb_order.at[qarange, exclude].set(_INF)
+            lb = compute_bounds(q, index, cascade, k=k, plan=plan)
+    w = cascade.w
 
     # ---- work-conserving flat verification scheduler -------------------
     # The naive per-query round scheme wastes whole rounds on finished
@@ -508,12 +496,8 @@ def _search(
     # unverified ranks, so stragglers soak up the slots finished queries
     # no longer need (up to the static gather cap T_max = 8*M).  Total DTW
     # compute tracks the semantic verified count instead of rounds*Q*M.
-    order = jnp.argsort(lb_order, axis=1)                 # (Q, N)
-    slb = jnp.take_along_axis(lb_order, order, axis=1)
-    slb_pad = jnp.pad(slb, ((0, 0), (0, 1)), constant_values=_INF)
     P = Q * M
     T_max = min(N, 8 * M)
-    jarange = jnp.arange(P)
     max_rounds = -(-Q * N // P) + 2
     bound_sched = plan.schedule == "bound"
     # per-round pair-tile sizing: bound-ordered rounds cluster their
@@ -525,6 +509,56 @@ def _search(
         plan.verify_tile_p if plan.verify_tile_p is not None
         else sched_pair_tile(P)
     ) if bound_sched else plan.verify_tile_p
+    tier_stats = None
+    guard0 = None
+    with obs.span("engine.order"):
+        qarange = jnp.arange(Q)
+        if cascade.staged:
+            tier_stats = cres.stats
+            guard0 = cres.guard
+            lb = cres.lb
+            # seeds are already verified: warm-start the top-k with them
+            # and drop them from the unverified ordering
+            sel = jnp.argsort(cres.seed_d, axis=1)
+            best_d0 = jnp.take_along_axis(cres.seed_d, sel, axis=1)
+            best_i0 = jnp.take_along_axis(cres.seed_idx, sel, axis=1)
+            n_dtw0 = jnp.full((Q,), k, jnp.int32)
+            if gon and g.finite_gates:
+                # a gated (+inf) seed was never really verified: leave its
+                # bound in the ordering so the loop verifies the candidate
+                # instead of losing it behind the seed mask
+                cur = jnp.take_along_axis(lb, cres.seed_idx, axis=1)
+                lb_order = lb.at[qarange[:, None], cres.seed_idx].set(
+                    jnp.where(jnp.isfinite(cres.seed_d), _INF, cur)
+                )
+            else:
+                lb_order = lb.at[qarange[:, None], cres.seed_idx].set(
+                    _INF)
+        else:
+            best_d0 = jnp.full((Q, k), _INF, jnp.float32)
+            best_i0 = jnp.full((Q, k), -1, jnp.int32)
+            n_dtw0 = jnp.zeros((Q,), jnp.int32)
+            lb_order = lb
+        if exclude is not None:
+            lb = lb.at[qarange, exclude].set(_INF)
+            lb_order = lb_order.at[qarange, exclude].set(_INF)
+
+        order = jnp.argsort(lb_order, axis=1)                 # (Q, N)
+        slb = jnp.take_along_axis(lb_order, order, axis=1)
+        slb_pad = jnp.pad(slb, ((0, 0), (0, 1)), constant_values=_INF)
+        jarange = jnp.arange(P)
+        # queries whose seeded k-th best already certifies against the
+        # smallest unverified bound never enter the loop
+        done0 = best_d0[:, k - 1] <= slb_pad[:, 0]
+        state = (
+            jnp.int32(0),
+            best_d0,
+            best_i0,
+            n_dtw0,
+            jnp.zeros((Q,), jnp.int32),
+            done0,
+            jnp.zeros((6,), jnp.float32),
+        )
 
     def body(state):
         r, best_d, best_i, n_dtw, cursor, done, gacc = state
@@ -632,39 +666,34 @@ def _search(
         r, _, _, _, _, done, _ = state
         return (r < max_rounds) & ~jnp.all(done)
 
-    # queries whose seeded k-th best already certifies against the smallest
-    # unverified bound never enter the loop
-    done0 = best_d0[:, k - 1] <= slb_pad[:, 0]
-    state = (
-        jnp.int32(0),
-        best_d0,
-        best_i0,
-        n_dtw0,
-        jnp.zeros((Q,), jnp.int32),
-        done0,
-        jnp.zeros((6,), jnp.float32),
-    )
-    _, best_d, best_i, n_dtw, _, _, gacc = lax.while_loop(cond, body, state)
+    with obs.span("engine.verify"):
+        r, best_d, best_i, n_dtw, _, _, gacc = lax.while_loop(cond, body,
+                                                              state)
+        obs.count_rounds(r)
     guard = None
     if gon:
-        guard = dataclasses.replace(
-            _g.GuardReport.zeros(),
-            admiss_checked=gacc[0], admiss_viol=gacc[1], admiss_gap=gacc[2],
-            account_checked=gacc[3], account_viol=gacc[4],
-            nonfinite_dtw=gacc[5],
-        )
-        if g.accounting:
-            # end-of-search bounds: every query verified at least its
-            # seeds (staged) and never more than the whole store
-            floor = k if cascade.staged else 0
-            bv = jnp.sum((n_dtw > N) | (n_dtw < floor)).astype(jnp.float32)
+        # the loop's guard counters merge into the cascade's report
+        with obs.span("nn_search.guards"):
             guard = dataclasses.replace(
-                guard,
-                account_checked=guard.account_checked + float(Q),
-                account_viol=guard.account_viol + bv,
+                _g.GuardReport.zeros(),
+                admiss_checked=gacc[0], admiss_viol=gacc[1],
+                admiss_gap=gacc[2],
+                account_checked=gacc[3], account_viol=gacc[4],
+                nonfinite_dtw=gacc[5],
             )
-        if guard0 is not None:
-            guard = guard0.merge(guard)
+            if g.accounting:
+                # end-of-search bounds: every query verified at least its
+                # seeds (staged) and never more than the whole store
+                floor = k if cascade.staged else 0
+                bv = jnp.sum((n_dtw > N) | (n_dtw < floor)).astype(
+                    jnp.float32)
+                guard = dataclasses.replace(
+                    guard,
+                    account_checked=guard.account_checked + float(Q),
+                    account_viol=guard.account_viol + bv,
+                )
+            if guard0 is not None:
+                guard = guard0.merge(guard)
     return SearchResult(dists=best_d, idx=best_i, n_dtw=n_dtw, lb=lb), \
         tier_stats, guard
 

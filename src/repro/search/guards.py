@@ -64,8 +64,9 @@ DESIGN — trace-compatibility rules
      the same contract as the adaptive budget and the planner.
   4. On clean finite data every gate is the identity, so guarded and
      unguarded runs are bit-equal (property-tested); guards change
-     *work* by a priced, CI-bounded amount (``guard_overhead_*`` bench
-     rows, <= 5% on the bound pass), never results.
+     *work*, never results.  Their host sync on the chip is the
+     benchmark's ``guard_sync_ms_per_request.online`` (the span
+     ``repro.nn_search.guards``; PERF.md and the ledger).
 
 DESIGN — degradation ladder
 ---------------------------
@@ -140,9 +141,10 @@ class GuardWarning(UserWarning):
 class GuardConfig:
     """Which invariant checks run, and the degradation policy.
 
-    Default-on: the checks are priced (``guard_overhead_*`` bench rows,
-    CI-guarded <= 5% on the bound pass) and cheap enough to leave on in
-    serving.  ``REPRO_FORCE_GUARDS=1`` in the environment forces every
+    Default-on: the checks are cheap enough to leave on in serving;
+    what their host sync costs on the chip is the benchmark's
+    ``guard_sync_ms_per_request.online`` (PERF.md and the ledger).
+    ``REPRO_FORCE_GUARDS=1`` in the environment forces every
     check on regardless of the config (the CI fault-injection job).
 
     Attributes:
