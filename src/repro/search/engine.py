@@ -46,7 +46,9 @@ metric counts: ``P = 1 - n_dtw / N``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +56,7 @@ import numpy as np
 from jax import lax
 
 from repro import obs
-from repro.kernels.ops import dtw_band_op
+from repro.kernels.ops import _interpret, dtw_band_op
 from repro.kernels.ref import dtw_band_ref
 from repro.kernels.tiling import sched_pair_tile, unpermute_pairs
 from repro.search import guards as _g
@@ -447,6 +449,186 @@ def nn_search(
         return res, report
 
 
+@dataclasses.dataclass(frozen=True)
+class _LoopKey:
+    """What the verification loop is traced from besides its arguments'
+    shapes: one compiled program per key and shapes (``_verify``).
+
+    The fault seams and the kernels' interpret mode are read while the
+    loop is traced, so they key the program too (hooks by identity): an
+    injected fault never runs a clean program, nor a clean call a faulty
+    one."""
+
+    k: int
+    P: int
+    T_max: int
+    max_rounds: int
+    w: int
+    bound_sched: bool
+    round_tile: int | None
+    dtw_fn: Callable
+    guards: bool
+    finite_gates: bool
+    admissibility: bool
+    accounting: bool
+    rtol: float
+    atol: float
+    engine_count: Callable | None
+    dtw_out: Callable | None
+    interpret: bool | None
+
+
+def _shapes(*arrays) -> tuple:
+    return tuple((tuple(x.shape), x.dtype)
+                 for x in jax.tree_util.tree_leaves(arrays))
+
+
+# warnings raised while a loop program was traced, by key and shapes:
+# ``_verify`` raises them again on every call that reuses the program
+_trace_warnings: dict = {}
+_warn_registry: dict = {}
+
+
+def _verify(key: _LoopKey, q, series, order, slb, slb_pad, state):
+    """Run the verification loop through its cached program, raising the
+    warnings its tracing raised (a kernel fallback among them) each time."""
+    out = _verify_loop(key, q, series, order, slb, slb_pad, state)
+    for w in _trace_warnings.get(
+            (key, _shapes(q, series, order, slb, slb_pad, state)), ()):
+        warnings.warn_explicit(w.message, w.category, w.filename, w.lineno,
+                               registry=_warn_registry)
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _verify_loop(key: _LoopKey, q, series, order, slb, slb_pad, state):
+    """Bound-ordered rounds of banded DTW until every query certifies.
+
+    Every array the loop reads is an argument, so no store or bound
+    matrix is embedded in the program.  Returns ``(rounds, best_d,
+    best_i, n_dtw, guard accumulators)``.
+    """
+    Q = q.shape[0]
+    N = series.shape[0]
+    k, P, T_max, w = key.k, key.P, key.T_max, key.w
+    dtw_fn = key.dtw_fn
+    qarange = jnp.arange(Q)
+    jarange = jnp.arange(P)
+
+    def body(state):
+        r, best_d, best_i, n_dtw, cursor, done, gacc = state
+        n_un = jnp.maximum(jnp.sum(~done), 1)
+        quota = jnp.minimum(P // n_un, T_max)             # ranks per query
+        qorder = jnp.argsort(done)                        # undone first
+        pos = jnp.argsort(qorder)                         # query -> stripe
+        qi = qorder[jarange % n_un]                       # (P,) slot query
+        stripe = jarange // n_un
+        rank = cursor[qi] + stripe
+        valid = (~done[qi]) & (rank < N) & (stripe < quota)
+        rank_c = jnp.minimum(rank, N - 1)
+        cidx = order[qi, rank_c]                          # candidate ids
+        # exactly-+inf-sorted ranks are masked-out entries (verified
+        # seeds / excluded candidates) — never re-verify them, or their
+        # results would duplicate existing top-k members.  Only +inf is
+        # an intentional mask: NaN or -inf there means a poisoned bound,
+        # and those candidates must STAY eligible so a bad bound
+        # degrades to verification (safe) instead of silent exclusion
+        # (wrong answers) — guards.verification_eligible
+        valid = valid & _g.verification_eligible(slb[qi, rank_c])
+        lbv = jnp.where(valid, slb[qi, rank_c], _INF)
+        kth0 = best_d[:, k - 1]
+        # thread each query's current k-th best into the kernel's per-pair
+        # early-abandon cutoff: lanes that cannot beat it return +inf
+        if key.bound_sched:
+            # bound-ordered packing: argsort the flat batch ascending by
+            # its tightest bound so the loosest (most-doomed) pairs share
+            # pair tiles; invalid slots sort last (+inf bound) and get a
+            # -inf cutoff so they die at the first block boundary instead
+            # of pinning their tile's liveness flag.  The permutation is
+            # composed into the slot->row index gathers (one (P, L)
+            # gather per operand, same packing the ops' ``perm=`` gather
+            # would produce) and inverted on the (P,) output — everything
+            # below sees the original slot order.
+            perm = jnp.argsort(lbv)
+            cut = jnp.where(valid, kth0[qi], -_INF)[perm]
+            dp = dtw_fn(q[qi[perm]], series[cidx[perm]], w, cut,
+                        tile_p=key.round_tile)
+            d = unpermute_pairs(perm, dp)                 # (P,) flat
+        else:
+            # round_tile is None here unless the plan pinned verify_tile_p
+            d = dtw_fn(q[qi], series[cidx], w, kth0[qi],
+                       tile_p=key.round_tile)             # (P,)
+        z32 = jnp.zeros((), jnp.float32)
+        a_chk = a_vio = a_gap = acc_chk = acc_vio = nf_dtw = z32
+        if key.guards and key.finite_gates:
+            # a NaN verification value would poison the top-k merge:
+            # gate it to +inf (cannot enter the top-k) and count it —
+            # nn_search's degradation decides whether +inf was safe
+            d, nf_dtw = _g.finite_gate_dtw(d, valid=valid)
+        d = jnp.where(valid, d, _INF)
+        if key.guards and key.admissibility:
+            # every verified lane doubles as an admissibility sample:
+            # its tier bound must not exceed its exact DTW
+            a_chk, a_vio, a_gap = _g.admissibility_check(
+                lbv, d, key.rtol, key.atol, valid=valid
+            )
+        # per-query gather of this round's results (stripe layout)
+        t = jnp.arange(T_max)
+        slots = pos[:, None] + t[None, :] * n_un          # (Q, T_max)
+        ok = (t[None, :] < quota) & (slots < P)
+        slots_c = jnp.minimum(slots, P - 1)
+        gd = jnp.where(ok & (qi[slots_c] == qarange[:, None]),
+                       d[slots_c], _INF)
+        gi = cidx[slots_c]
+        alld = jnp.concatenate([best_d, gd], axis=1)
+        alli = jnp.concatenate([best_i, gi], axis=1)
+        neg, sel = lax.top_k(-alld, k)
+        best_d = -neg
+        best_i = jnp.take_along_axis(alli, sel, axis=1)
+        # semantic count (the paper's pruning-power numerator): a slot is a
+        # *necessary* verification if its bound still beats the post-round
+        # k-th best (the sequential loop could not have skipped it) or it
+        # entered the top-k.  Counting against the pre-round k-th best
+        # would charge slots the sequential loop skips once the earlier
+        # candidates of the same round have updated the running best.
+        kth1 = best_d[:, k - 1]
+        active = valid & ((lbv < kth1[qi]) | (d <= kth1[qi]))
+        inc = active.astype(jnp.int32)
+        seg = jax.ops.segment_sum(inc, qi, num_segments=Q)
+        if key.engine_count is not None:
+            seg = key.engine_count(seg)
+        if key.guards and key.accounting:
+            # the per-query scatter must conserve the flat liveness
+            # mirror's total — a dropped or double-counted slot here is
+            # the while-loop miscompile's accounting signature
+            acc_chk = jnp.asarray(1.0, jnp.float32)
+            acc_vio = (jnp.sum(seg) != jnp.sum(inc)).astype(jnp.float32)
+        n_dtw = n_dtw + seg
+        cursor = jnp.minimum(cursor + jnp.where(~done, quota, 0), N)
+        next_lb = slb_pad[qarange, cursor]
+        done = done | (best_d[:, k - 1] <= next_lb) | (cursor >= N)
+        if key.guards:
+            gacc = jnp.stack([
+                gacc[0] + a_chk, gacc[1] + a_vio,
+                jnp.maximum(gacc[2], a_gap),
+                gacc[3] + acc_chk, gacc[4] + acc_vio,
+                gacc[5] + nf_dtw,
+            ])
+        return r + 1, best_d, best_i, n_dtw, cursor, done, gacc
+
+    def cond(state):
+        r, _, _, _, _, done, _ = state
+        return (r < key.max_rounds) & ~jnp.all(done)
+
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        r, best_d, best_i, n_dtw, _, _, gacc = lax.while_loop(cond, body,
+                                                              state)
+    _trace_warnings[(key, _shapes(q, series, order, slb, slb_pad,
+                                  state))] = caught
+    return r, best_d, best_i, n_dtw, gacc
+
+
 def _search(
     index: DTWIndex,
     queries: Array,
@@ -546,7 +728,6 @@ def _search(
         order = jnp.argsort(lb_order, axis=1)                 # (Q, N)
         slb = jnp.take_along_axis(lb_order, order, axis=1)
         slb_pad = jnp.pad(slb, ((0, 0), (0, 1)), constant_values=_INF)
-        jarange = jnp.arange(P)
         # queries whose seeded k-th best already certifies against the
         # smallest unverified bound never enter the loop
         done0 = best_d0[:, k - 1] <= slb_pad[:, 0]
@@ -560,115 +741,19 @@ def _search(
             jnp.zeros((6,), jnp.float32),
         )
 
-    def body(state):
-        r, best_d, best_i, n_dtw, cursor, done, gacc = state
-        n_un = jnp.maximum(jnp.sum(~done), 1)
-        quota = jnp.minimum(P // n_un, T_max)             # ranks per query
-        qorder = jnp.argsort(done)                        # undone first
-        pos = jnp.argsort(qorder)                         # query -> stripe
-        qi = qorder[jarange % n_un]                       # (P,) slot query
-        stripe = jarange // n_un
-        rank = cursor[qi] + stripe
-        valid = (~done[qi]) & (rank < N) & (stripe < quota)
-        rank_c = jnp.minimum(rank, N - 1)
-        cidx = order[qi, rank_c]                          # candidate ids
-        # exactly-+inf-sorted ranks are masked-out entries (verified
-        # seeds / excluded candidates) — never re-verify them, or their
-        # results would duplicate existing top-k members.  Only +inf is
-        # an intentional mask: NaN or -inf there means a poisoned bound,
-        # and those candidates must STAY eligible so a bad bound
-        # degrades to verification (safe) instead of silent exclusion
-        # (wrong answers) — guards.verification_eligible
-        valid = valid & _g.verification_eligible(slb[qi, rank_c])
-        lbv = jnp.where(valid, slb[qi, rank_c], _INF)
-        kth0 = best_d[:, k - 1]
-        # thread each query's current k-th best into the kernel's per-pair
-        # early-abandon cutoff: lanes that cannot beat it return +inf
-        if bound_sched:
-            # bound-ordered packing: argsort the flat batch ascending by
-            # its tightest bound so the loosest (most-doomed) pairs share
-            # pair tiles; invalid slots sort last (+inf bound) and get a
-            # -inf cutoff so they die at the first block boundary instead
-            # of pinning their tile's liveness flag.  The permutation is
-            # composed into the slot->row index gathers (one (P, L)
-            # gather per operand, same packing the ops' ``perm=`` gather
-            # would produce) and inverted on the (P,) output — everything
-            # below sees the original slot order.
-            perm = jnp.argsort(lbv)
-            cut = jnp.where(valid, kth0[qi], -_INF)[perm]
-            dp = dtw_fn(q[qi[perm]], index.series[cidx[perm]], w, cut,
-                        tile_p=round_tile)
-            d = unpermute_pairs(perm, dp)                 # (P,) flat
-        else:
-            # round_tile is None here unless the plan pinned verify_tile_p
-            d = dtw_fn(q[qi], index.series[cidx], w, kth0[qi],
-                       tile_p=round_tile)                 # (P,)
-        z32 = jnp.zeros((), jnp.float32)
-        a_chk = a_vio = a_gap = acc_chk = acc_vio = nf_dtw = z32
-        if gon and g.finite_gates:
-            # a NaN verification value would poison the top-k merge:
-            # gate it to +inf (cannot enter the top-k) and count it —
-            # nn_search's degradation decides whether +inf was safe
-            d, nf_dtw = _g.finite_gate_dtw(d, valid=valid)
-        d = jnp.where(valid, d, _INF)
-        if gon and g.admissibility:
-            # every verified lane doubles as an admissibility sample:
-            # its tier bound must not exceed its exact DTW
-            a_chk, a_vio, a_gap = _g.admissibility_check(
-                lbv, d, g.rtol, g.atol, valid=valid
-            )
-        # per-query gather of this round's results (stripe layout)
-        t = jnp.arange(T_max)
-        slots = pos[:, None] + t[None, :] * n_un          # (Q, T_max)
-        ok = (t[None, :] < quota) & (slots < P)
-        slots_c = jnp.minimum(slots, P - 1)
-        gd = jnp.where(ok & (qi[slots_c] == qarange[:, None]),
-                       d[slots_c], _INF)
-        gi = cidx[slots_c]
-        alld = jnp.concatenate([best_d, gd], axis=1)
-        alli = jnp.concatenate([best_i, gi], axis=1)
-        neg, sel = lax.top_k(-alld, k)
-        best_d = -neg
-        best_i = jnp.take_along_axis(alli, sel, axis=1)
-        # semantic count (the paper's pruning-power numerator): a slot is a
-        # *necessary* verification if its bound still beats the post-round
-        # k-th best (the sequential loop could not have skipped it) or it
-        # entered the top-k.  Counting against the pre-round k-th best
-        # would charge slots the sequential loop skips once the earlier
-        # candidates of the same round have updated the running best.
-        kth1 = best_d[:, k - 1]
-        active = valid & ((lbv < kth1[qi]) | (d <= kth1[qi]))
-        inc = active.astype(jnp.int32)
-        seg = jax.ops.segment_sum(inc, qi, num_segments=Q)
-        hook_cnt = _g.fault_hook("engine_count")
-        if hook_cnt is not None:
-            seg = hook_cnt(seg)
-        if gon and g.accounting:
-            # the per-query scatter must conserve the flat liveness
-            # mirror's total — a dropped or double-counted slot here is
-            # the while-loop miscompile's accounting signature
-            acc_chk = jnp.asarray(1.0, jnp.float32)
-            acc_vio = (jnp.sum(seg) != jnp.sum(inc)).astype(jnp.float32)
-        n_dtw = n_dtw + seg
-        cursor = jnp.minimum(cursor + jnp.where(~done, quota, 0), N)
-        next_lb = slb_pad[qarange, cursor]
-        done = done | (best_d[:, k - 1] <= next_lb) | (cursor >= N)
-        if gon:
-            gacc = jnp.stack([
-                gacc[0] + a_chk, gacc[1] + a_vio,
-                jnp.maximum(gacc[2], a_gap),
-                gacc[3] + acc_chk, gacc[4] + acc_vio,
-                gacc[5] + nf_dtw,
-            ])
-        return r + 1, best_d, best_i, n_dtw, cursor, done, gacc
-
-    def cond(state):
-        r, _, _, _, _, done, _ = state
-        return (r < max_rounds) & ~jnp.all(done)
-
+    key = _LoopKey(
+        k=k, P=P, T_max=T_max, max_rounds=max_rounds, w=w,
+        bound_sched=bound_sched, round_tile=round_tile, dtw_fn=dtw_fn,
+        guards=gon, finite_gates=g.finite_gates,
+        admissibility=g.admissibility, accounting=g.accounting,
+        rtol=g.rtol, atol=g.atol,
+        engine_count=_g.fault_hook("engine_count"),
+        dtw_out=_g.fault_hook("dtw_out"),
+        interpret=_interpret() if dtw_fn is dtw_band_op else None,
+    )
     with obs.span("engine.verify"):
-        r, best_d, best_i, n_dtw, _, _, gacc = lax.while_loop(cond, body,
-                                                              state)
+        r, best_d, best_i, n_dtw, gacc = _verify(
+            key, q, index.series, order, slb, slb_pad, state)
         obs.count_rounds(r)
     guard = None
     if gon:

@@ -14,6 +14,7 @@ from repro import obs
 from repro.data import make_dataset
 from repro.search import (CascadeConfig, EngineConfig, GuardWarning,
                           build_index, nn_search)
+from repro.search import engine
 from repro.testing import faults
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -66,22 +67,21 @@ def test_calls_count_outermost_spans_only():
     assert _delta(before, obs.snapshot(), "calls") == 2
 
 
-def test_a_fresh_search_credits_its_lowering_to_verify():
+def test_a_fresh_search_lowers_in_verify_and_a_warm_one_lowers_nothing():
     ds, idx, cfg = _setup()
+    engine._verify_loop.clear_cache()     # fresh: no loop program yet
     s0 = obs.snapshot()
     nn_search(idx, ds.x_test, cfg)
     s1 = obs.snapshot()
-    assert s1["lowerings_by_span"].get("repro.engine.verify", 0) > \
-        s0["lowerings_by_span"].get("repro.engine.verify", 0)
+    assert s1["lowerings_by_span"].get("repro.engine.verify", 0) == \
+        s0["lowerings_by_span"].get("repro.engine.verify", 0) + 1
     assert s1["lowering_s"] > s0["lowering_s"]
-    # warm: every eager op is cached; the loop's closure is new each call
+    # warm: every eager op and the loop's program are cached
     nn_search(idx, ds.x_test, cfg)
     s2 = obs.snapshot()
-    grew = {name: n - s1["lowerings_by_span"].get(name, 0)
-            for name, n in s2["lowerings_by_span"].items()
-            if n != s1["lowerings_by_span"].get(name, 0)}
-    assert grew == {"repro.engine.verify": 1}
-    assert _delta(s1, s2, "lowerings") == 1
+    assert s2["lowerings_by_span"] == s1["lowerings_by_span"]
+    assert _delta(s1, s2, "lowerings") == 0
+    assert _delta(s1, s2, "lowering_s") == 0
 
 
 def test_no_lowering_is_counted_outside_a_span():
@@ -216,8 +216,8 @@ def test_trace_counters_cover_the_calls_made_while_tracing(tmp_path):
         jax.profiler.stop_trace()
     trace = obs.snapshot()["trace"]
     assert trace["calls"] == 2
-    assert trace["lowerings"] == 2
-    assert trace["lowerings_by_span"] == {"repro.engine.verify": 2}
+    assert trace["lowerings"] == 0
+    assert trace["lowerings_by_span"] == {}
     spans = trace["span_s"]
     for name in ("nn_search", "nn_search.hygiene", "engine.bounds",
                  "engine.order", "engine.verify", "nn_search.guards"):
