@@ -64,10 +64,11 @@ against.
 
 Streaming grid (``stream=True``): the resident grid above keeps the whole
 packed operands ``a2p``/``b2p`` (``~2 * TP * (2L + Wb)`` f32) in VMEM for
-the entire sweep, which is what used to cap ``dtw_band_op`` at
-``_DTW_MAX_L = 16384``.  The streaming kernel removes the length ceiling
-by leaving the operands in HBM (``MemorySpace.ANY``) and turning
-the row-block grid into a true DMA pipeline: row block ``j`` only ever
+the entire sweep, which is why ``dtw_band_op`` runs it only up to the
+residency crossover ``ops._DTW_RESIDENT_MAX_L = 16384``.  The streaming
+kernel has no length ceiling: it leaves the operands in HBM
+(``MemorySpace.ANY``) and turns the row-block grid into a true DMA
+pipeline: row block ``j`` only ever
 touches the operand windows ``a2p[:, jR : jR + R + Wb)`` and
 ``b2p[:, 2L - min(D, (j+1)R) : ... + R + Wb)``, so each ``(pair_tile,
 row_block)`` step double-buffers those windows — block ``j + 1``'s async
@@ -92,13 +93,19 @@ windows of ``Wwin ~ R + Wb + 128`` lanes (``tiling.stream_window``), plus
 the 2-buffer frontier and ~4
 ``band_step`` temporaries of ``Wb`` lanes — ``(4 Wwin + ~8 Wb) * TP * 4``
 bytes, independent of series length.  ``tiling.stream_geometry`` picks
-the largest ``(tile_p, R)`` that fits ``_VMEM_BUDGET`` (preferring the
-shared ``row_block_policy`` block so abandon boundaries match the
-reference, halving ``R`` in 64-step multiples when the window is too
-wide); only when the band state itself (``~8 Wb`` lanes at the 8-sublane
-floor) exceeds the budget — e.g. ``w = L`` at ``L = 64k`` — does ops.py
-fall back to the jnp reference.  L=65536, w=0.01L (Wb=1408): policy picks
-TP=24, R=16384 — ~7.9 MB, where the resident layout would need ~550 MB.
+the largest ``(tile_p, R)`` that fits the chip's streaming budget
+(``tiling.stream_vmem_budget``, 32 MiB of a v5e's 128 MiB of VMEM;
+the kernel passes the matching ``vmem_limit_bytes``, 96 MiB, since
+Mosaic's default scoped limit is far below it), preferring the shared
+``row_block_policy`` block so abandon boundaries match the reference and
+halving ``R`` in 64-step multiples when the window is too wide.  Only
+when the band state itself (``~8 Wb`` lanes at the 8-sublane floor)
+exceeds the budget — ``w = L`` at ``L = 64k`` — does ops.py fall back
+to the jnp reference.  The full band at L=17984 (``w = L``, Wb=35968)
+fits: R=4544 and TP=16 hold ~28.8 MB, where the old 10 MB budget, at
+13.8 MB for the smallest block at the sublane floor, refused it.
+L=65536, w=0.01L (Wb=1408): the policy picks TP=96, R=16384 for a
+batch of 1024 pairs.
 """
 
 from __future__ import annotations
@@ -111,19 +118,21 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.dtw import band_step, row_block_policy
+from repro.core.dtw import band_step
 from repro.kernels.tiling import (
     Wb_pad,
-    pick_pair_tile,
+    resident_geometry,
     round_up,
     stream_geometry,
+    stream_vmem_budget,
+    stream_vmem_limit,
     stream_window,
 )
 
 Array = jax.Array
 
 _INF = float(jnp.inf)
-_VMEM_BUDGET = 10 * 2**20          # bytes for packed operands + DP state
+_VMEM_BUDGET = 10 * 2**20          # resident grid: packed operands + state
 
 
 def _lane_window(ref, off, width: int):
@@ -401,11 +410,9 @@ def dtw_band_pallas(
             a, b, wb, cutoff, tile_p=tile_p, interpret=interpret,
             row_block=row_block,
         )
-    # every aligned window load (_lane_window) stays inside the operand
-    pad_len = round_up(2 * L + Wb + max(wb, 128), 128)
     # auto-shrink the pair tile so packed operands + state fit VMEM
-    per_row = (2 * pad_len + 8 * Wb) * 4
-    tile_p = pick_pair_tile(tile_p, P, per_row, _VMEM_BUDGET)
+    tile_p, R, pad_len = resident_geometry(L, wb, tile_p, P, _VMEM_BUDGET,
+                                           row_block=row_block)
     a2p, b2p, cutoff, Pp = _pack_band_operands(a, b, cutoff, wb, pad_len,
                                                tile_p)
     # pairs on the sublane axis: (tile_p, 1) blocks stay legal for the
@@ -426,10 +433,7 @@ def dtw_band_pallas(
             interpret=interpret,
         )(a2p, b2p, cutoff)
         return out[:P, 0]
-    D = 2 * L - 1
-    R = row_block if row_block is not None else row_block_policy(L)
-    R = max(1, min(R, D))
-    n_blocks = -(-D // R)
+    n_blocks = -(-(2 * L - 1) // R)
     out = pl.pallas_call(
         functools.partial(_dtw_band_kernel_blocked, L=L, w=wb, Wb=Wb, R=R),
         grid=(Pp // tile_p, n_blocks),
@@ -464,7 +468,7 @@ def _dtw_band_pallas_stream(
     P, L = a.shape
     Wb = Wb_pad(wb)
     D = 2 * L - 1
-    geom = stream_geometry(L, wb, tile_p, P, _VMEM_BUDGET,
+    geom = stream_geometry(L, wb, tile_p, P, stream_vmem_budget(),
                            row_block=row_block)
     if geom is None:
         raise ValueError(
@@ -507,6 +511,8 @@ def _dtw_band_pallas_stream(
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=stream_vmem_limit()),
         interpret=interpret,
     )(a2p, b2p, cutoff)
     return out[:P, 0]

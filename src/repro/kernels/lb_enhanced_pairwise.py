@@ -7,9 +7,9 @@ of the problem the cross-block kernel (lb_enhanced.py) solves: there every
 query row meets every candidate row and the output is a ``(TQ, TC)``
 block.  Running the cross-block kernel on compacted survivors would pay
 ``TQ x TC`` work for a diagonal's worth of answers, so this kernel
-specialises the *pairwise* shape instead: one ``(TP, L)`` tile of queries,
-candidates and candidate envelopes in, one ``(TP,)`` vector of bounds out,
-a single VMEM round trip per tile.
+specialises the *pairwise* shape instead: a tile of ``TP`` packed pairs
+(their queries, candidates' band columns and candidate envelopes) in,
+one ``(TP,)`` vector of bounds out.
 
 Band structure is identical to the cross-block kernel (paper SS III):
 band ``i < nb`` is L-shaped with arm width ``i + 1 <= nb``, and because
@@ -35,9 +35,20 @@ compacted packing keeps one query's slots contiguous, so light-shard
 queries produce whole dead tiles and the budget allocation turns into
 genuinely skipped work, not masked outputs.
 
-VMEM: q/c/u/lo are ``4 * TP * L`` f32 plus ``O(TP)`` accumulators.
-TP=128, L=4096 -> ~8.4 MB; ``tile_p`` auto-shrinks (multiples of 8) to
-stay inside ``_VMEM_BUDGET`` for longer series.
+L blocks: as in the cross-block kernel, the bands read only the first
+and last ``nb`` columns of each row, passed as ``(P, 2nb)`` blocks of
+their own, and the Keogh bridge runs on a second grid axis over blocks
+of ``TL = tiling.lb_block_l(L)`` columns, the last and reduction axis:
+the ``(TP, 1)`` output block stays in VMEM across it, the first L block
+writes the bands and every block adds its share of the bridge.  Up to
+``LB_BLOCK_L`` columns the row is one block (a static bridge slice, the
+tile as before the axis existed); past it each block masks its columns
+to the bridge.  ``bands_only`` reads the band columns alone.
+
+VMEM: the q/u/lo blocks are ``3 * TP * TL`` f32, double-buffered, plus
+``O(TP)`` accumulators.  TP=128, TL=2048 -> ~6.3 MB; ``tile_p``
+auto-shrinks (multiples of 8) to keep ``4 * TP * TL`` f32 inside
+``_VMEM_BUDGET``.
 """
 
 from __future__ import annotations
@@ -49,74 +60,116 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import pick_pair_tile
+from repro.kernels.tiling import l_block_keep, lb_block_l, pick_pair_tile
 
 Array = jax.Array
 
 _INF = float(jnp.inf)
-_VMEM_BUDGET = 8 * 2**20           # bytes for the four (TP, L) operands
+_VMEM_BUDGET = 8 * 2**20           # bytes for four (TP, TL) row blocks
 
 
-def _bands_and_bridge(q_ref, c_ref, u_ref, l_ref, *, nb: int,
-                      bands_only: bool, dt):
-    """(TP, 1) LB_ENHANCED^V accumulator for one pair tile (shared by
-    the live-gated and ungated kernel bodies); pairs stay on the sublane
-    axis, like the (tile_p, 1) out and live blocks."""
-    q = q_ref[...]                                      # (TP, L)
-    c = c_ref[...]
-    L = q.shape[1]
-    acc = jnp.zeros((q.shape[0], 1), dtype=dt)
-    # --- elastic bands: each arm is a contiguous column slice ---
+def _bands(qt, ct, nb: int, dt):
+    """(TP, 1) elastic band minima of one pair tile.  ``qt``/``ct`` are
+    the ``(TP, 2nb)`` band columns: index ``s < nb`` is column ``s``,
+    index ``2nb-1-s`` column ``L-1-s``; pairs stay on the sublane axis,
+    like the (tile_p, 1) out and live blocks."""
+    acc = jnp.zeros((qt.shape[0], 1), dtype=dt)
     for bi in range(nb):
-        ir = L - 1 - bi
+        rr = 2 * nb - 1 - bi
         # left band bi: cells (a_t, b_bi) and (a_bi, b_t) for t <= bi
-        dl1 = q[:, :bi + 1] - c[:, bi:bi + 1]
-        dl2 = q[:, bi:bi + 1] - c[:, :bi + 1]
+        dl1 = qt[:, :bi + 1] - ct[:, bi:bi + 1]
+        dl2 = qt[:, bi:bi + 1] - ct[:, :bi + 1]
         ml = jnp.min(jnp.minimum(dl1 * dl1, dl2 * dl2), axis=-1,
                      keepdims=True)
-        # right band (mirror around L-1): columns [ir, L)
-        dr1 = q[:, ir:] - c[:, ir:ir + 1]
-        dr2 = q[:, ir:ir + 1] - c[:, ir:]
+        # right band (mirror around L-1): columns [L-1-bi, L)
+        dr1 = qt[:, rr:] - ct[:, rr:rr + 1]
+        dr2 = qt[:, rr:rr + 1] - ct[:, rr:]
         mr = jnp.min(jnp.minimum(dr1 * dr1, dr2 * dr2), axis=-1,
                      keepdims=True)
         acc = acc + ml + mr
-    # --- Keogh bridge over [nb, L - nb) ---
-    if not bands_only:
-        qb = q[:, nb:L - nb]
-        over = jnp.maximum(qb - u_ref[:, nb:L - nb], 0.0)
-        under = jnp.maximum(l_ref[:, nb:L - nb] - qb, 0.0)
-        acc = acc + jnp.sum(over * over + under * under, axis=-1,
-                            keepdims=True)
     return acc
 
 
-def _lb_enhanced_pairwise_kernel(
-    q_ref, c_ref, u_ref, l_ref, out_ref, *, nb: int, bands_only: bool
-):
-    out_ref[...] = _bands_and_bridge(
-        q_ref, c_ref, u_ref, l_ref, nb=nb, bands_only=bands_only,
-        dt=out_ref.dtype,
-    )
+def _tile(qt_ref, ct_ref, q_ref, u_ref, l_ref, out_ref, l_blk, *, nb: int,
+          L: int, n_l: int, live=None):
+    """Add this grid step's share of one pair tile's (TP, 1) bounds
+    (shared by the live-gated and ungated kernel bodies): the bands at
+    the first L block, then the block's Keogh bridge columns (none with
+    ``bands_only``, where ``q_ref`` is ``None``); ``live`` masks dead
+    slots to ``-inf`` at the last L block.  ``l_blk`` is the L-block
+    index, read at the kernel's top level (interpret mode lowers
+    ``pl.program_id`` only there), of ``n_l`` blocks."""
+    dt = out_ref.dtype
+
+    def bands():
+        out_ref[...] = _bands(qt_ref[...], ct_ref[...], nb, dt)
+
+    def mask():
+        out_ref[...] = jnp.where(live, out_ref[...], -_INF)
+
+    if q_ref is None:
+        bands()
+        if live is not None:
+            mask()
+        return
+
+    def bridge():
+        # --- Keogh bridge over [nb, L - nb) ---
+        TL = q_ref.shape[1]
+        keep = l_block_keep(L, TL, nb, L - nb, l_blk)
+        cols = slice(nb, L - nb) if keep is None else slice(None)
+        qb = q_ref[:, cols]
+        over = jnp.maximum(qb - u_ref[:, cols], 0.0)
+        under = jnp.maximum(l_ref[:, cols] - qb, 0.0)
+        cell = over * over + under * under
+        if keep is not None:
+            cell = jnp.where(keep, cell, 0.0)
+        out_ref[...] = out_ref[...] + jnp.sum(cell, axis=-1, keepdims=True)
+
+    if n_l == 1:
+        bands()
+        bridge()
+        if live is not None:
+            mask()
+        return
+    pl.when(l_blk == 0)(bands)
+    bridge()
+    if live is not None:
+        pl.when(l_blk == n_l - 1)(mask)
 
 
-def _lb_enhanced_pairwise_kernel_live(
-    q_ref, c_ref, u_ref, l_ref, live_ref, out_ref, flag_ref, *, nb: int,
-    bands_only: bool
-):
-    """Live-gated tile: dead slots emit -inf, all-dead tiles skip the
+def _lb_enhanced_pairwise_kernel(*refs, nb: int, L: int, n_l: int,
+                                 bands_only: bool, gated: bool):
+    """One grid step.  Operands: the band columns ``qt``/``ct``, then
+    (unless ``bands_only``) the ``q``/``u``/``lo`` L blocks, then (when
+    ``gated``) the ``(TP, 1)`` liveness block; outputs: the bounds, then
+    (when ``gated``) an SMEM flag scratch.
+
+    Live-gated tile: dead slots emit -inf, all-dead tiles skip the
     band/bridge compute entirely (SMEM flag + ``pl.when``, the DTW tiles'
     liveness mechanism)."""
+    qt_ref, ct_ref = refs[:2]
+    q_ref = u_ref = l_ref = None
+    if not bands_only:
+        q_ref, u_ref, l_ref = refs[2:5]
+    l_blk = pl.program_id(1) if n_l > 1 else 0
+    rest = refs[2 if bands_only else 5:]
+    kw = dict(nb=nb, L=L, n_l=n_l)
+    if not gated:
+        _tile(qt_ref, ct_ref, q_ref, u_ref, l_ref, rest[0], l_blk, **kw)
+        return
+    live_ref, out_ref, flag_ref = rest
     live = live_ref[...] != 0                           # (TP, 1)
     flag_ref[0] = jnp.any(live).astype(jnp.int32)
-    out_ref[...] = jnp.full(out_ref.shape, -_INF, out_ref.dtype)
+
+    @pl.when(flag_ref[0] == 0)
+    def _dead():
+        out_ref[...] = jnp.full(out_ref.shape, -_INF, out_ref.dtype)
 
     @pl.when(flag_ref[0] == 1)
     def _compute():
-        acc = _bands_and_bridge(
-            q_ref, c_ref, u_ref, l_ref, nb=nb, bands_only=bands_only,
-            dt=out_ref.dtype,
-        )
-        out_ref[...] = jnp.where(live, acc, -_INF)
+        _tile(qt_ref, ct_ref, q_ref, u_ref, l_ref, out_ref, l_blk, live=live,
+              **kw)
 
 
 @functools.partial(
@@ -144,65 +197,66 @@ def lb_enhanced_pairwise_pallas(
     """
     P, L = q.shape
     nb = max(0, min(L // 2, w, v))
-    # auto-shrink the pair tile so the four operands fit VMEM
-    tile_p = pick_pair_tile(tile_p, P, 4 * L * 4, _VMEM_BUDGET)
+    TL = lb_block_l(L)
+    n_l = pl.cdiv(L, TL)
+    # auto-shrink the pair tile so four (TP, TL) blocks fit VMEM
+    tile_p = pick_pair_tile(tile_p, P, 4 * TL * 4, _VMEM_BUDGET)
     if live is not None:
         live = jnp.broadcast_to(jnp.asarray(live), (P,)).astype(jnp.int32)
+    # band columns of each row; one dummy column when there are no bands
+    # keeps the blocks non-empty
+    if nb:
+        qt = jnp.concatenate([q[:, :nb], q[:, L - nb:]], axis=1)
+        ct = jnp.concatenate([c[:, :nb], c[:, L - nb:]], axis=1)
+    else:
+        qt = ct = jnp.zeros((P, 1), q.dtype)
     pp = (-P) % tile_p
     if pp:
-        q = jnp.pad(q, ((0, pp), (0, 0)))
-        c = jnp.pad(c, ((0, pp), (0, 0)))
-        u = jnp.pad(u, ((0, pp), (0, 0)), constant_values=_INF)
-        lo = jnp.pad(lo, ((0, pp), (0, 0)), constant_values=-_INF)
+        pad = ((0, pp), (0, 0))
+        qt, ct = jnp.pad(qt, pad), jnp.pad(ct, pad)
+        if not bands_only:
+            q = jnp.pad(q, pad)
+            u = jnp.pad(u, pad, constant_values=_INF)
+            lo = jnp.pad(lo, pad, constant_values=-_INF)
         if live is not None:
             # pad slots are dead, so they never hold a tile's flag up
             live = jnp.pad(live, (0, pp))
     Pp = P + pp
     # pairs on the sublane axis: (tile_p, 1) blocks, legal at any tile
     out_shape = jax.ShapeDtypeStruct((Pp, 1), q.dtype)
-    row_spec = pl.BlockSpec((tile_p, L), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((tile_p, 1), lambda i: (i, 0))
+    pair_spec = pl.BlockSpec((tile_p, 1), lambda i, *_: (i, 0))
+    band_spec = pl.BlockSpec((tile_p, qt.shape[1]), lambda i, *_: (i, 0))
+    row_spec = pl.BlockSpec((tile_p, TL), lambda i, l: (i, l))
+    operands, in_specs = [qt, ct], [band_spec, band_spec]
+    if not bands_only:
+        operands += [q, u, lo]
+        in_specs += [row_spec] * 3
+    scratch = []
     if live is not None:
-        live = live[:, None]
+        operands.append(live[:, None])
+        in_specs.append(pair_spec)
+        scratch = [pltpu.SMEM((1,), jnp.int32)]
+    kern = functools.partial(
+        _lb_enhanced_pairwise_kernel, nb=nb, L=L,
+        n_l=1 if bands_only else n_l, bands_only=bands_only,
+        gated=live is not None)
     # single-tile batches drop the grid entirely: the tile is the whole
     # problem, so the grid scaffolding (index maps, per-step block
     # slicing) is pure overhead — this is what puts the kernel ahead of
     # the fused jnp path at the bench shape (P=128, L=256)
-    single = Pp == tile_p
-    if live is None:
-        kern = functools.partial(
-            _lb_enhanced_pairwise_kernel, nb=nb, bands_only=bands_only
-        )
-        if single:
-            out = pl.pallas_call(kern, out_shape=out_shape,
-                                 interpret=interpret)(q, c, u, lo)
-        else:
-            out = pl.pallas_call(
-                kern,
-                grid=(Pp // tile_p,),
-                in_specs=[row_spec] * 4,
-                out_specs=out_spec,
-                out_shape=out_shape,
-                interpret=interpret,
-            )(q, c, u, lo)
+    if Pp == tile_p and (bands_only or n_l == 1):
+        out = pl.pallas_call(kern, out_shape=out_shape,
+                             scratch_shapes=scratch,
+                             interpret=interpret)(*operands)
     else:
-        kern = functools.partial(
-            _lb_enhanced_pairwise_kernel_live, nb=nb, bands_only=bands_only
-        )
-        scratch = [pltpu.SMEM((1,), jnp.int32)]
-        if single:
-            out = pl.pallas_call(
-                kern, out_shape=out_shape, scratch_shapes=scratch,
-                interpret=interpret,
-            )(q, c, u, lo, live)
-        else:
-            out = pl.pallas_call(
-                kern,
-                grid=(Pp // tile_p,),
-                in_specs=[row_spec] * 4 + [out_spec],
-                out_specs=out_spec,
-                out_shape=out_shape,
-                scratch_shapes=scratch,
-                interpret=interpret,
-            )(q, c, u, lo, live)
+        grid = (Pp // tile_p,) + (() if bands_only else (n_l,))
+        out = pl.pallas_call(
+            kern,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pair_spec,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+        )(*operands)
     return out[:P, 0]

@@ -5,11 +5,17 @@ Dispatch policy:
   * on CPU they run in ``interpret=True`` mode, which executes the kernel
     bodies in Python for bit-faithful validation;
   * any other backend raises: there is no silent third path;
-  * shapes outside kernel limits (very long series that exceed the VMEM
-    budget documented in each kernel) fall back to the pure-jnp reference
-    with a ``KernelFallbackWarning`` (once per kernel and shape), so the
-    public API never fails on shape grounds and a fallback is never
-    silent.
+  * shapes outside kernel limits (an envelope longer than
+    ``_ENVELOPE_MAX_L``, a DTW band whose state exceeds VMEM) fall back to
+    the pure-jnp reference with a ``KernelFallbackWarning`` (once per
+    kernel and shape) and the ``repro.obs`` counter ``kernel_fallbacks``,
+    so the public API never fails on shape grounds and a fallback is
+    never silent.  The bound kernels have no length limit: past
+    ``tiling.LB_BLOCK_L`` columns they reduce over L blocks.
+
+Every call of a bound op counts its grid (``bound_grid``: ``resident`` or
+``l_blocked``) and every banded-DTW call its grid (``verify_grid``:
+``resident`` or ``stream``) in ``repro.obs``.
 
 All entry points accept/return plain arrays and are safe to ``jax.jit``
 (and to call inside ``shard_map`` — see search/distributed.py).
@@ -23,8 +29,8 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ref
-from repro.kernels.dtw_band import _VMEM_BUDGET as _DTW_VMEM_BUDGET
 from repro.kernels.dtw_band import dtw_band_pallas
 from repro.kernels.envelope import envelope_pallas
 from repro.kernels.lb_enhanced import lb_enhanced_pallas
@@ -32,16 +38,23 @@ from repro.kernels.lb_enhanced_pairwise import lb_enhanced_pairwise_pallas
 from repro.kernels.lb_keogh import lb_keogh_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
 from repro.kernels.sketch import sketch_bound_pallas
-from repro.kernels.tiling import apply_pair_perm, stream_geometry
+from repro.kernels.tiling import (
+    apply_pair_perm,
+    lb_block_l,
+    stream_geometry,
+    stream_vmem_budget,
+)
 
 Array = jax.Array
 
-# VMEM-derived shape limits (see per-kernel headers for the budgets)
+# The envelope kernel holds 3 blocks of (8, L) f32 rows, double-buffered:
+# 6 MB at this length (kernels/envelope.py); longer series fall back.
 _ENVELOPE_MAX_L = 65536
-_LB_MAX_L = 16384
 # Above this length the packed DTW operands stop being VMEM-resident and
-# dtw_band_op switches to the streaming DMA-pipeline grid — there is no
-# length ceiling any more, only this residency crossover.
+# dtw_band_op switches to the streaming DMA-pipeline grid.  That grid has
+# no length ceiling: only a band whose state alone exceeds the chip's
+# streaming budget at the sublane floor falls back (w = L at L = 64k;
+# w = L at L = 17984 fits a v5e, tiling.stream_vmem_budget).
 _DTW_RESIDENT_MAX_L = 16384
 
 
@@ -66,11 +79,23 @@ def _fallback(kernel: str, shape, why: str) -> None:
     The message names the kernel and the shape, so the warnings module's
     per-message registry shows it once per kernel and shape.
     """
+    obs.count("kernel_fallbacks", kernel)
     warnings.warn(
         f"{kernel}: shape {tuple(shape)} served by the jnp reference ({why})",
         KernelFallbackWarning,
         stacklevel=3,
     )
+
+
+def _count_grid(counter: str, grid: str, x) -> None:
+    """Count one op call under its grid (``repro.obs``); a call traced
+    into a program counts only where the program replays it."""
+    obs.count(counter, grid, traced=isinstance(x, jax.core.Tracer))
+
+
+def _bound_grid(q, bands_only: bool = False) -> None:
+    blocked = not bands_only and lb_block_l(q.shape[-1]) < q.shape[-1]
+    _count_grid("bound_grid", "l_blocked" if blocked else "resident", q)
 
 
 def envelope_op(b: Array, w: int) -> tuple[Array, Array]:
@@ -89,9 +114,7 @@ def envelope_op(b: Array, w: int) -> tuple[Array, Array]:
 
 def lb_keogh_op(q: Array, u: Array, lo: Array) -> Array:
     """``(Q, L) x (C, L) envelopes -> (Q, C)`` LB_KEOGH matrix."""
-    if q.shape[-1] > _LB_MAX_L:
-        _fallback("lb_keogh", q.shape, f"L > {_LB_MAX_L}")
-        return ref.lb_keogh_ref(q, u, lo)
+    _bound_grid(q)
     return lb_keogh_pallas(q, u, lo, interpret=_interpret())
 
 
@@ -107,11 +130,7 @@ def lb_enhanced_op(
     with the pairwise kernel, so the planner can limit-mask a dense
     cross-block tier too (see kernels/lb_enhanced.py).
     """
-    if q.shape[-1] > _LB_MAX_L:
-        _fallback("lb_enhanced", q.shape, f"L > {_LB_MAX_L}")
-        return ref.lb_enhanced_ref(
-            q, c, u, lo, w, v, live=live, bands_only=bands_only
-        )
+    _bound_grid(q, bands_only)
     return lb_enhanced_pallas(
         q, c, u, lo, w, v, live=live, bands_only=bands_only,
         interpret=_interpret(),
@@ -133,11 +152,7 @@ def lb_enhanced_pairwise_op(
     pair tiles skip their compute — the global survivor budget's refine
     limits become skipped work, not masked outputs.
     """
-    if q.shape[-1] > _LB_MAX_L:
-        _fallback("lb_enhanced_pairwise", q.shape, f"L > {_LB_MAX_L}")
-        return ref.lb_enhanced_pairwise_ref(
-            q, c, u, lo, w, v, live=live, bands_only=bands_only
-        )
+    _bound_grid(q, bands_only)
     return lb_enhanced_pairwise_pallas(
         q, c, u, lo, w, v, live=live, bands_only=bands_only,
         interpret=_interpret(),
@@ -193,13 +208,17 @@ def dtw_band_op(
     Length dispatch: series up to ``_DTW_RESIDENT_MAX_L`` run the
     VMEM-resident grid; longer series run the streaming DMA pipeline
     (operands in HBM, double-buffered per-block windows — no length
-    ceiling).  The streaming grid *is* the liveness grid, so past the
-    crossover ``early_exit=False`` is ignored — the PR 1 baseline is a
-    VMEM-resident kernel by construction and only exists below the
-    crossover (benchmark it there).  Only shapes whose *band state*
-    exceeds VMEM at the sublane floor (``stream_geometry`` returns None,
-    e.g. w = L at L = 64k) fall back to the jnp reference, so the public
-    API never fails on shape grounds.
+    ceiling), sized to the chip's streaming budget
+    (``tiling.stream_vmem_budget``: 32 MiB of a v5e's 128 MiB VMEM, under
+    an explicit 96 MiB ``vmem_limit_bytes``).  The streaming grid *is*
+    the liveness grid, so past the crossover ``early_exit=False`` is
+    ignored — the no-early-exit baseline is a VMEM-resident kernel by
+    construction and only exists below the crossover (benchmark it
+    there).  Only
+    shapes whose *band state* exceeds that budget at the sublane floor
+    (``stream_geometry`` returns None: w = L at L = 64k, where w = L at
+    L = 17984 fits) fall back to the jnp reference, so the public API
+    never fails on shape grounds.
     """
     if perm is not None:
         return apply_pair_perm(
@@ -211,16 +230,18 @@ def dtw_band_op(
     tp = 128 if tile_p is None else tile_p
     if L > _DTW_RESIDENT_MAX_L:
         wb = min(L if (w is None or w >= L) else w, L - 1)
-        if stream_geometry(L, wb, tp, P, _DTW_VMEM_BUDGET) is None:
+        if stream_geometry(L, wb, tp, P, stream_vmem_budget()) is None:
             _fallback("dtw_band", a.shape,
                       f"band state of w={wb} exceeds VMEM")
             out = ref.dtw_band_ref(a, b, w, cutoff)
         else:
+            _count_grid("verify_grid", "stream", a)
             out = dtw_band_pallas(
                 a, b, w, cutoff, stream=True, tile_p=tp,
                 interpret=_interpret(),
             )
     else:
+        _count_grid("verify_grid", "resident", a)
         out = dtw_band_pallas(
             a, b, w, cutoff, early_exit=early_exit, tile_p=tp,
             interpret=_interpret(),

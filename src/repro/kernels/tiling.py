@@ -54,6 +54,39 @@ def apply_pair_perm(fn, perm: Array, a: Array, b: Array,
     return unpermute_pairs(perm, fn(pa, pb, pcut))
 
 
+# VMEM of one v5e TensorCore, what the budgets below assume where no chip
+# is attached (the CPU tests, a compile for a described v5e)
+_V5E_VMEM_BYTES = 128 * 2**20
+
+
+def chip_vmem_bytes() -> int:
+    """VMEM of one TensorCore of the default device: the chip's own
+    figure on tpu (``pltpu.get_tpu_info``), a v5e's 128 MiB elsewhere."""
+    if jax.default_backend() == "tpu":
+        from jax.experimental.pallas import tpu as pltpu
+
+        try:
+            return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+        except ValueError:          # a device kind the table lacks
+            pass
+    return _V5E_VMEM_BYTES
+
+
+def stream_vmem_limit() -> int:
+    """``vmem_limit_bytes`` of the streaming DTW kernel: three quarters of
+    the core's VMEM (96 MiB on a v5e), far above Mosaic's default scoped
+    limit, so a full band's state (``w = L``) can stay on chip."""
+    return chip_vmem_bytes() * 3 // 4
+
+
+def stream_vmem_budget() -> int:
+    """Working-set budget ``stream_geometry`` sizes the streaming grid to:
+    a third of ``stream_vmem_limit`` (32 MiB on a v5e).  The rest of the
+    limit is headroom for what the estimate leaves out: Mosaic's spills of
+    the ``band_step`` temporaries past the ~8 ``Wb`` it counts."""
+    return stream_vmem_limit() // 3
+
+
 def pick_pair_tile(tile_p: int, P: int, per_row_bytes: int,
                    budget_bytes: int) -> int:
     """Largest pair-tile <= ``tile_p`` (multiple of 8, floor 8) whose
@@ -61,6 +94,57 @@ def pick_pair_tile(tile_p: int, P: int, per_row_bytes: int,
     batch is a single tile."""
     tile_p = min(tile_p, max(8, (budget_bytes // per_row_bytes) // 8 * 8))
     return min(tile_p, round_up(P, 8))
+
+
+def resident_geometry(L: int, wb: int, tile_p: int, P: int,
+                      budget_bytes: int, row_block: int | None = None
+                      ) -> tuple[int, int, int]:
+    """``(tile, R, pad_len)`` of the VMEM-resident DTW grid: the pair tile
+    whose two packed operands of ``pad_len`` lanes plus ~8 ``Wb`` of band
+    state fit ``budget_bytes``, the shared ``row_block_policy`` block, and
+    the operand width that keeps every aligned window load
+    (kernels/dtw_band.py ``_lane_window``) inside the operand."""
+    from repro.core.dtw import row_block_policy
+
+    Wb = Wb_pad(wb)
+    pad_len = round_up(2 * L + Wb + max(wb, 128), 128)
+    tile = pick_pair_tile(tile_p, P, (2 * pad_len + 8 * Wb) * 4,
+                          budget_bytes)
+    D = 2 * L - 1
+    R = row_block if row_block is not None else row_block_policy(L)
+    return tile, max(1, min(R, D)), pad_len
+
+
+# The bound kernels (lb_keogh, lb_enhanced, lb_enhanced_pairwise) hold
+# blocks of whole rows up to this many columns; a longer series is cut
+# into blocks of this width along a reduction grid axis, the partial
+# sums kept in the output block.  Per grid step the widest of them, the
+# pairwise kernel (its tile sized for four (128, TL) f32 row blocks; it
+# reads three), then holds at most 4 x 128 x 2048 x 4 B = 4 MiB, 8 MiB
+# double-buffered: inside Mosaic's default scoped VMEM (16 MiB on a v5e)
+# at any length.
+LB_BLOCK_L = 2048
+
+
+def lb_block_l(L: int) -> int:
+    """Columns per L block of the bound kernels: the whole series up to
+    ``LB_BLOCK_L`` (one block, the kernels' tiles unchanged), else
+    ``LB_BLOCK_L`` (a 128-lane multiple), the last block ragged."""
+    return L if L <= LB_BLOCK_L else LB_BLOCK_L
+
+
+def l_block_keep(L: int, TL: int, lo: int, hi: int, l_blk):
+    """``(1, TL)`` mask of the columns of L block ``l_blk`` (the kernel's
+    ``pl.program_id`` of its L-block axis, read at the kernel's top level)
+    that lie in ``[lo, hi)`` of the series, or ``None`` when the series is
+    one block and static slices serve instead.  ``hi <= L`` also drops
+    the ragged last block's columns past ``L``, whose values a partial
+    block leaves undefined (``jnp.where``, so a NaN there stays out).
+    Shared by the three L-blocked bound kernels."""
+    if TL == L:
+        return None
+    col = l_blk * TL + jax.lax.broadcasted_iota(jnp.int32, (1, TL), 1)
+    return (col >= lo) & (col < hi)
 
 
 # sketch kernel output block: queries on sublanes, candidates on lanes

@@ -23,6 +23,19 @@ Counters, process-wide, read with ``snapshot()``:
                  host sync, device program or device affinity to a search
   span_s         wall time of each span, timed only while a profiler
                  trace runs
+  kernel_fallbacks  kernel op calls served by their jnp reference, by
+                 kernel (``kernels/ops.py``)
+  bound_grid     calls of the L-blockable bound ops (LB_Keogh and both
+                 LB_ENHANCED kernels), by grid: ``resident`` (whole rows
+                 in one block) or ``l_blocked`` (a reduction axis over L
+                 blocks)
+  verify_grid    calls of the banded-DTW op, by grid: ``resident`` or
+                 ``stream``
+
+The last three are counted by ``count`` as an op is called.  An op traced
+into a compiled program counts nothing at trace time, except inside
+``recording()``: there its counts are kept, and the program's caller
+``replay``s them on every call of the program (``engine._verify``).
 
 ``snapshot()["trace"]`` holds the same counters over the calls made
 since the running (or last) profiler trace began.  Tracing is on
@@ -35,6 +48,7 @@ closes.
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
 import time
 
@@ -56,6 +70,7 @@ class _Counts:
         self.lowering_s_by_span = collections.Counter()
         self.span_s = collections.Counter()
         self.verify_rounds = 0
+        self.keyed = {name: collections.Counter() for name in KEYED}
 
     def read(self) -> dict:
         return {"calls": self.calls, "lowerings": self.lowerings,
@@ -63,7 +78,12 @@ class _Counts:
                 "lowerings_by_span": dict(self.lowerings_by_span),
                 "lowering_s_by_span": dict(self.lowering_s_by_span),
                 "verify_rounds": self.verify_rounds,
-                "span_s": dict(self.span_s)}
+                "span_s": dict(self.span_s),
+                **{name: dict(c) for name, c in self.keyed.items()}}
+
+
+# the counters kept by key (module docstring)
+KEYED = ("kernel_fallbacks", "bound_grid", "verify_grid")
 
 
 _lock = threading.Lock()
@@ -174,6 +194,40 @@ def count_rounds(r) -> None:
         return
     r.copy_to_host_async()
     _rounds.append((r, _active()))
+
+
+def count(name: str, key: str, *, traced: bool = False) -> None:
+    """Add one to the keyed counter ``name`` (one of ``KEYED``) under
+    ``key``.  ``traced``: the op runs on traced values, so it is counted
+    only inside ``recording()``, which keeps it for ``replay``."""
+    rec = getattr(_local, "recording", None)
+    if rec is not None:
+        rec.append((name, key))
+        return
+    if traced:
+        return
+    with _lock:
+        for c in _active():
+            c.keyed[name][key] += 1
+
+
+@contextlib.contextmanager
+def recording():
+    """Keep this thread's ``count`` calls in the list yielded instead of
+    adding them up: a program traced inside counts its ops once per call
+    by ``replay``-ing the list."""
+    prev = getattr(_local, "recording", None)
+    _local.recording = rec = []
+    try:
+        yield rec
+    finally:
+        _local.recording = prev
+
+
+def replay(records) -> None:
+    """Count again what a ``recording()`` kept."""
+    for name, key in records:
+        count(name, key)
 
 
 def snapshot() -> dict:

@@ -483,20 +483,24 @@ def _shapes(*arrays) -> tuple:
                  for x in jax.tree_util.tree_leaves(arrays))
 
 
-# warnings raised while a loop program was traced, by key and shapes:
-# ``_verify`` raises them again on every call that reuses the program
+# warnings raised and ``repro.obs`` counts kept while a loop program was
+# traced (its DTW op's grid, a kernel fallback), by key and shapes:
+# ``_verify`` raises and counts them again on every call of the program
 _trace_warnings: dict = {}
+_trace_counts: dict = {}
 _warn_registry: dict = {}
 
 
 def _verify(key: _LoopKey, q, series, order, slb, slb_pad, state):
     """Run the verification loop through its cached program, raising the
-    warnings its tracing raised (a kernel fallback among them) each time."""
+    warnings its tracing raised (a kernel fallback among them) and
+    counting the ops its tracing recorded, each time."""
     out = _verify_loop(key, q, series, order, slb, slb_pad, state)
-    for w in _trace_warnings.get(
-            (key, _shapes(q, series, order, slb, slb_pad, state)), ()):
+    traced = (key, _shapes(q, series, order, slb, slb_pad, state))
+    for w in _trace_warnings.get(traced, ()):
         warnings.warn_explicit(w.message, w.category, w.filename, w.lineno,
                                registry=_warn_registry)
+    obs.replay(_trace_counts.get(traced, ()))
     return out
 
 
@@ -620,12 +624,14 @@ def _verify_loop(key: _LoopKey, q, series, order, slb, slb_pad, state):
         r, _, _, _, _, done, _ = state
         return (r < key.max_rounds) & ~jnp.all(done)
 
-    with warnings.catch_warnings(record=True) as caught:
+    with warnings.catch_warnings(record=True) as caught, \
+            obs.recording() as counts:
         warnings.simplefilter("always")
         r, best_d, best_i, n_dtw, _, _, gacc = lax.while_loop(cond, body,
                                                               state)
-    _trace_warnings[(key, _shapes(q, series, order, slb, slb_pad,
-                                  state))] = caught
+    traced = (key, _shapes(q, series, order, slb, slb_pad, state))
+    _trace_warnings[traced] = caught
+    _trace_counts[traced] = counts
     return r, best_d, best_i, n_dtw, gacc
 
 

@@ -25,7 +25,11 @@ import pytest
 from repro.core.dtw import dtw_band_blocked, row_block_policy
 from repro.kernels import ops, ref
 from repro.kernels.dtw_band import _VMEM_BUDGET, dtw_band_pallas
-from repro.kernels.tiling import sched_pair_tile, stream_geometry
+from repro.kernels.tiling import (
+    sched_pair_tile,
+    stream_geometry,
+    stream_vmem_budget,
+)
 
 L_SMALL = 129                       # odd: exercises parity masking
 
@@ -149,12 +153,13 @@ def test_dtw_band_op_streams_past_residency(rng):
 
 
 def test_stream_unfittable_band_falls_back_to_ref(rng, monkeypatch):
-    """w so wide the band state alone exceeds VMEM at the sublane floor:
-    stream_geometry says None and the op routes to the jnp reference.
-    (Executing that shape is O(L^2) work on any path — too costly for a
-    test — so the dispatch decision is asserted via a sentinel.)"""
-    L = ops._DTW_RESIDENT_MAX_L + 8
-    assert stream_geometry(L, L - 1, 128, 2, _VMEM_BUDGET) is None
+    """w so wide the band state alone exceeds the chip's streaming budget
+    at the sublane floor (w = L at L = 64k): stream_geometry says None and
+    the op routes to the jnp reference.  (Executing that shape is O(L^2)
+    work on any path — too costly for a test — so the dispatch decision
+    is asserted via a sentinel.)"""
+    L = 65536
+    assert stream_geometry(L, L - 1, 128, 2, stream_vmem_budget()) is None
     P = 2
     a, b = _pair(rng, P, L)
     sentinel = jnp.full((P,), 42.0, jnp.float32)

@@ -1,5 +1,7 @@
 """Compile every served Pallas kernel for a v5e chip, at the paper's
-deployment shapes (configs/paper_dtw.py: L=512, w=154, V=4), without a
+deployment shapes (configs/paper_dtw.py: L=512, w=154, V=4) and at the
+long-series deployment's (UEA EigenWorms: L=17984, w=L, through the
+L-blocked bound kernels and the full-band streaming DTW grid), without a
 chip: the TPU compiler is installed and compiles for a described
 topology.  Interpret mode (the CPU suite) never checks Mosaic's layout
 rules; these tests do, at about a second per kernel.
@@ -29,6 +31,8 @@ from repro.kernels.lb_keogh import lb_keogh_pallas
 from repro.kernels.sketch import sketch_bound_pallas
 
 L, W, V = PAPER_SEARCH.length, PAPER_SEARCH.w, PAPER_SEARCH.v
+LONG = 17984              # bench/configs/uea_eigenworms.json, w = L
+MID = 8192
 F32, I32, I8 = jnp.float32, jnp.int32, jnp.int8
 
 # name -> (kernel call, argument shapes); every call compiles for the chip
@@ -66,6 +70,44 @@ CASES = {
     "lb_keogh": (
         lb_keogh_pallas,
         [((64, L), F32), ((1024, L), F32), ((1024, L), F32)]),
+    # one query against a 128-series store at full window: the rounds'
+    # pair batch streams a 35968-lane band state; the bound kernels
+    # reduce over nine L blocks
+    "dtw_band_stream_full_band_L17984": (
+        lambda a, b, cut, **kw: dtw_band_pallas(a, b, LONG, cut,
+                                                stream=True, tile_p=32,
+                                                **kw),
+        [((32, LONG), F32), ((32, LONG), F32), ((32,), F32)]),
+    "lb_enhanced_pairwise_live_L17984": (
+        lambda q, c, u, lo, live, **kw: lb_enhanced_pairwise_pallas(
+            q, c, u, lo, LONG, V, live=live, **kw),
+        [((128, LONG), F32)] * 4 + [((128,), I32)]),
+    "lb_enhanced_L17984": (
+        functools.partial(lb_enhanced_pallas, w=LONG, v=V),
+        [((8, LONG), F32)] + [((128, LONG), F32)] * 3),
+    "lb_keogh_L17984": (
+        lb_keogh_pallas,
+        [((8, LONG), F32), ((128, LONG), F32), ((128, LONG), F32)]),
+    "envelope_full_window_L17984": (
+        functools.partial(envelope_pallas, w=LONG),
+        [((128, LONG), F32)]),
+    # a store of many tiles at L=8192: bound kernels that held whole
+    # (tile, L) rows, double-buffered across grid steps, ran out of
+    # Mosaic's 16 MiB scoped VMEM here (the pairwise kernel from L=4096)
+    "lb_keogh_L8192_store1024": (
+        lb_keogh_pallas,
+        [((64, MID), F32), ((1024, MID), F32), ((1024, MID), F32)]),
+    "lb_enhanced_bands_live_L8192_store1024": (
+        lambda q, c, u, lo, live, **kw: lb_enhanced_pallas(
+            q, c, u, lo, MID, V, live=live, bands_only=True, **kw),
+        [((64, MID), F32)] + [((1024, MID), F32)] * 3 + [((1024,), I32)]),
+    "lb_enhanced_L8192_store1024": (
+        functools.partial(lb_enhanced_pallas, w=MID, v=V),
+        [((64, MID), F32)] + [((1024, MID), F32)] * 3),
+    "lb_enhanced_pairwise_L8192_P1024": (
+        lambda q, c, u, lo, **kw: lb_enhanced_pairwise_pallas(
+            q, c, u, lo, MID, V, **kw),
+        [((1024, MID), F32)] * 4),
 }
 
 

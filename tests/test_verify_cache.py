@@ -139,7 +139,7 @@ def test_a_fallback_warning_fires_on_every_reuse(monkeypatch):
     # the loop's DTW shape falls back to the jnp reference: past the
     # (lowered) residency limit, with no band state that fits VMEM
     monkeypatch.setattr(ops, "_DTW_RESIDENT_MAX_L", 16)
-    monkeypatch.setattr(ops, "_DTW_VMEM_BUDGET", 1)
+    monkeypatch.setattr(ops, "stream_vmem_budget", lambda: 1)
     idx, q = _store()
     cfg = _cfg()
 
