@@ -31,11 +31,14 @@ Counters, process-wide, read with ``snapshot()``:
                  blocks)
   verify_grid    calls of the banded-DTW op, by grid: ``resident`` or
                  ``stream``
+  bound_pass     the engine's bound passes, by how they ran: ``program``
+                 (concrete inputs, one cached program per static key) or
+                 ``inline`` (traced inputs, counted as they are traced)
 
-The last three are counted by ``count`` as an op is called.  An op traced
-into a compiled program counts nothing at trace time, except inside
+The keyed counters are counted by ``count``.  An op traced into a
+compiled program counts nothing at trace time, except inside
 ``recording()``: there its counts are kept, and the program's caller
-``replay``s them on every call of the program (``engine._verify``).
+``replay``s them on every call of the program (``engine._call``).
 
 ``snapshot()["trace"]`` holds the same counters over the calls made
 since the running (or last) profiler trace began.  Tracing is on
@@ -83,7 +86,7 @@ class _Counts:
 
 
 # the counters kept by key (module docstring)
-KEYED = ("kernel_fallbacks", "bound_grid", "verify_grid")
+KEYED = ("kernel_fallbacks", "bound_grid", "verify_grid", "bound_pass")
 
 
 _lock = threading.Lock()

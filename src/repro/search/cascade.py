@@ -130,7 +130,10 @@ class CascadeConfig:
         on an index built with sketch features (``build_index`` computes
         them by default) — without features it scores an all-zero bound
         that the planner measures idle and drops.
-      candidate_chunk: candidates per fused-kernel invocation (VMEM tiling).
+      candidate_chunk: packed survivors per query in one chunk of the
+        pairwise tiers: only one chunk's ``(Q * chunk, L)`` gathered rows
+        are live at a time.  The all-pairs tiers make one kernel call over
+        the whole store; their kernels tile the candidates themselves.
       use_pallas: route the bound tiers through the Pallas kernels (True) or
         the pure-jnp references (False).  The jnp path is used when lowering
         the distributed search for the multi-pod dry-run, where kernel
@@ -176,9 +179,11 @@ class CascadeConfig:
         return min(n, _bucket_up(max(_BUDGET_FLOOR, 4 * k, -(-n // 8))))
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class CascadeResult:
-    """Tier-pipeline output consumed by the engine.
+    """Tier-pipeline output consumed by the engine (a pytree, so a
+    compiled bound pass returns it whole).
 
     Attributes:
       lb: (Q, N) per-pair lower bounds (all-pairs tiers everywhere,
@@ -215,14 +220,6 @@ def lb_kim_tier(q: Array, index: DTWIndex) -> Array:
     ok_min = jnp.where(q_mn <= c_mn, qok[:, None, 1], cok[None, :, 1])
     t_min = jnp.where(ok_min, d[..., 3], 0.0)
     return base + jnp.maximum(t_max, t_min)
-
-
-def _chunked(
-    fn, n: int, chunk: int
-):
-    """Map ``fn(start)`` over candidate chunks; concatenate on axis 1."""
-    outs = [fn(s) for s in range(0, n, chunk)]
-    return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
 
 
 def _accepts_kw(fn, name: str) -> bool:
@@ -356,8 +353,8 @@ def enhanced_all_pairs(
     *, live: Array | None = None,
 ) -> Array:
     """(Q, N) dense O(L) LB_ENHANCED tier — the ``enhanced_dense`` tier's
-    bound fn.  Chunked over candidates so each fused-kernel call matches
-    the VMEM tiling documented in kernels/lb_enhanced.py.
+    bound fn.  One kernel call over the whole store: the kernel grids
+    over candidate tiles itself (kernels/lb_enhanced.py).
 
     ``live`` (optional ``(N,)``) limit-masks the dense tier the way the
     refine limit masks the packed pairwise tiers: dead candidates come
@@ -365,23 +362,8 @@ def enhanced_all_pairs(
     tiles skip their compute in the kernel — the planner's lever for a
     cross-block tier whose mass does not pay everywhere.
     """
-    n = index.n
-    chunk = min(cfg.candidate_chunk, n)
-    lb_fn = cfg.lb_fn()
-
-    def tier2(s: int) -> Array:
-        e = min(s + chunk, n)
-        return lb_fn(
-            q,
-            index.series[s:e],
-            index.upper[s:e],
-            index.lower[s:e],
-            cfg.w,
-            cfg.v,
-            live=None if live is None else live[s:e],
-        )
-
-    return _chunked(tier2, n, chunk)
+    return cfg.lb_fn()(q, index.series, index.upper, index.lower, cfg.w,
+                       cfg.v, live=live)
 
 
 def run_plan(
@@ -414,12 +396,14 @@ def run_plan(
     every gate is the identity, so guarded results are bit-equal to
     unguarded ones (property-tested).  Their cost on the chip is in the
     benchmark's traced runs (``cascade_host_ms_per_request.batch`` holds
-    this executor's host time; PERF.md and the ledger).
+    the engine's host time around this pass; PERF.md and the ledger).
 
     Each all-pairs tier runs in the span ``repro.cascade.<tier name>``,
     the compaction in ``repro.cascade.compact``, the pairwise chunk loop
     in ``repro.cascade.pairwise`` and the seed verification in
-    ``repro.cascade.seeds`` (``repro.obs``).
+    ``repro.cascade.seeds`` (``repro.obs``).  Where the pass is traced
+    into a program (the engine compiles it once per static key,
+    engine.py ``_bound_pass``), these spans open only while it is traced.
 
     ``collect_stats`` makes the executor *instrumented*: it snapshots the
     running bound after every tier and, once the seeds fix the threshold
@@ -511,40 +495,48 @@ def run_plan(
                 c_checked, c_viol = c_checked + cc, c_viol + cv
 
         # ---- pairwise tiers on the packed survivor batches -------------
+        # Chunked over the packed width, so only one chunk's gathered
+        # (Q * chunk, L) rows are live at a time: a loop over the full
+        # chunks, then the ragged tail, each writing its columns of the
+        # (Q, W) result.  Every bound depends on its pair alone, so the
+        # chunking changes packing, never values.
         with obs.span("cascade.pairwise"):
             chunk = min(cfg.candidate_chunk, W)
-            cols = []
-            pw_snaps = [[] for _ in pairwise_tiers]   # per-tier running max
-            plive = None                   # live pair count under any masking
-            for s in range(0, W, chunk):
-                e = min(s + chunk, W)
-                cidx = cand[:, s:e].reshape(-1)          # (Q * bc,)
-                qf = jnp.repeat(q, e - s, axis=0)
+            # per-slot liveness from each query's refine allocation: the
+            # packed layout keeps one query's slots contiguous, so light
+            # queries yield whole dead pair tiles and the tier kernels
+            # skip them outright (dead slots come back -inf — the
+            # identity of the scatter-max below, so unrefined slots keep
+            # their cheap tier-0/1 bound).  The store-level mask ANDs in
+            # per *candidate*: a dead-store slot is dead in every
+            # query's allocation.
+            live_all = (None if limit is None
+                        else jnp.arange(W)[None, :] < limit[:, None])
+            if store_live is not None:
+                sl = store_live[cand]
+                live_all = sl if live_all is None else live_all & sl
+            plive = (None if live_all is None
+                     else jnp.sum(live_all).astype(jnp.float32))
+            hook_rows = _guards.fault_hook("packed_rows")
+
+            def refine(s, size):
+                """Packed columns ``[s, s + size)``: the (Q, size) running
+                pairwise max, its snapshot after each tier (stats only)
+                and the count of gated non-finite bounds."""
+                cidx = lax.dynamic_slice_in_dim(cand, s, size, 1).reshape(-1)
+                qf = jnp.repeat(q, size, axis=0)
                 crows = index.series[cidx]
                 urows = index.upper[cidx]
                 lrows = index.lower[cidx]
-                hook_rows = _guards.fault_hook("packed_rows")
                 if hook_rows is not None:
                     crows, urows, lrows = hook_rows(crows, urows, lrows)
-                # per-slot liveness from this query's refine allocation: the
-                # packed layout keeps one query's slots contiguous, so light
-                # queries yield whole dead pair tiles and the tier kernels
-                # skip them outright (dead slots come back -inf — the
-                # identity of the scatter-max below, so unrefined slots keep
-                # their cheap tier-0/1 bound).  The store-level mask ANDs in
-                # per *candidate*: a dead-store slot is dead in every
-                # query's allocation.
-                slot = jnp.arange(s, e)[None, :]
-                live2d = None if limit is None else (slot < limit[:, None])
-                if store_live is not None:
-                    sl = store_live[cidx].reshape(Q, e - s)
-                    live2d = sl if live2d is None else (live2d & sl)
+                live2d = (None if live_all is None
+                          else lax.dynamic_slice_in_dim(live_all, s, size, 1))
                 live = None if live2d is None else live2d.reshape(-1)
-                if live2d is not None:
-                    c = jnp.sum(live2d).astype(jnp.float32)
-                    plive = c if plive is None else plive + c
+                gated_n = z32
                 pe = None
-                for ti, tier in enumerate(pairwise_tiers):
+                snaps = []
+                for tier in pairwise_tiers:
                     if live is not None and _accepts_live(tier.fn):
                         t = tier.fn(qf, crows, urows, lrows, cfg, live=live)
                     else:   # no limit, or a pre-liveness custom tier
@@ -553,23 +545,42 @@ def run_plan(
                         t = hook_tier(t, tier.name)
                     if gon and g.finite_gates:
                         t, gated = _guards.finite_gate_bounds(t)
-                        nf_bounds = nf_bounds + gated
+                        gated_n = gated_n + gated
                     pe = t if pe is None else jnp.maximum(pe, t)
                     if collect_stats:
                         # running pairwise max after this tier, dead slots at
                         # the -inf scatter-max identity (the belt mask keeps
                         # pre-liveness custom tiers honest here too)
-                        snap = pe.reshape(Q, e - s)
+                        snap = pe.reshape(Q, size)
                         if live2d is not None:
                             snap = jnp.where(live2d, snap, -_INF)
-                        pw_snaps[ti].append(snap)
-                block = pe.reshape(Q, e - s)
+                        snaps.append(snap)
+                block = pe.reshape(Q, size)
                 if live2d is not None:
                     # belt for tiers without ``live`` support: the mask is
                     # idempotent over the kernel's own -inf dead slots
                     block = jnp.where(live2d, block, -_INF)
-                cols.append(block)
-            enh = jnp.concatenate(cols, axis=1) if len(cols) > 1 else cols[0]
+                return block, snaps, gated_n
+
+            def write(carry, s, size):
+                enh, snaps, nf = carry
+                block, new, gated = refine(s, size)
+
+                def put(buf, x):
+                    return lax.dynamic_update_slice_in_dim(buf, x, s, 1)
+
+                return (put(enh, block),
+                        [put(b, x) for b, x in zip(snaps, new)], nf + gated)
+
+            zeros = jnp.zeros((Q, W), q.dtype)
+            snaps0 = [zeros] * len(pairwise_tiers) if collect_stats else []
+            carry = (zeros, snaps0, nf_bounds)
+            n_full = W // chunk
+            carry = lax.fori_loop(
+                0, n_full, lambda i, c: write(c, i * chunk, chunk), carry)
+            if W % chunk:
+                carry = write(carry, n_full * chunk, W % chunk)
+            enh, pw_full, nf_bounds = carry
             lb = lb01.at[qarange[:, None], cand].max(enh)
             if gon and g.conservation:
                 mc, mv = _guards.scatter_monotone_check(lb01, lb)
@@ -685,11 +696,7 @@ def run_plan(
             )
             prev_pw = base
             for ti, tier in enumerate(pairwise_tiers):
-                pe_full = (
-                    jnp.concatenate(pw_snaps[ti], axis=1)
-                    if len(pw_snaps[ti]) > 1 else pw_snaps[ti][0]
-                )
-                cur_pw = jnp.maximum(base, pe_full)
+                cur_pw = jnp.maximum(base, pw_full[ti])
                 names.append(tier.name)
                 costs.append(tier.cost)
                 scopes.append(tier.scope)
@@ -760,23 +767,8 @@ def bands_prefilter(
 
     ``live`` (optional ``(N,)``) is the store-level candidate mask
     (search/index.py): dead candidates come back ``-inf`` and fully-dead
-    candidate tiles skip their compute in the kernel.
+    candidate tiles skip their compute in the kernel.  One kernel call
+    over the whole store, which reads only the band columns.
     """
-    n = index.n
-    chunk = min(cfg.candidate_chunk, n)
-    lb_fn = cfg.lb_fn()
-
-    def tier1(s: int) -> Array:
-        e = min(s + chunk, n)
-        return lb_fn(
-            q,
-            index.series[s:e],
-            index.upper[s:e],
-            index.lower[s:e],
-            cfg.w,
-            cfg.v,
-            live=None if live is None else live[s:e],
-            bands_only=True,
-        )
-
-    return _chunked(tier1, n, chunk)
+    return cfg.lb_fn()(q, index.series, index.upper, index.lower, cfg.w,
+                       cfg.v, live=live, bands_only=True)

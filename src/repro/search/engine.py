@@ -217,9 +217,9 @@ def _all_concrete(q: Array, index: DTWIndex,
                   exclude: Array | None) -> bool:
     """Whether every search input is a concrete (host) value.
 
-    The one definition behind both host-only gates — the adaptive budget
-    estimate and the planner's calibrate-then-commit — so they always
-    defer under tracing together."""
+    The one definition behind the host-only gates — the adaptive budget
+    estimate, the planner's calibrate-then-commit and the bound pass's
+    cached program — so they always defer under tracing together."""
     return not (
         isinstance(q, jax.core.Tracer)
         or isinstance(index.series, jax.core.Tracer)
@@ -452,7 +452,7 @@ def nn_search(
 @dataclasses.dataclass(frozen=True)
 class _LoopKey:
     """What the verification loop is traced from besides its arguments'
-    shapes: one compiled program per key and shapes (``_verify``).
+    shapes: one compiled program per key and shapes (``_verify_loop``).
 
     The fault seams and the kernels' interpret mode are read while the
     loop is traced, so they key the program too (hooks by identity): an
@@ -478,33 +478,89 @@ class _LoopKey:
     interpret: bool | None
 
 
+@dataclasses.dataclass(frozen=True)
+class _BoundsKey:
+    """What the bound pass is traced from besides its arguments' shapes:
+    one compiled program per key and shapes (``_bound_pass``).
+
+    The committed plan (its tiers' functions and its compaction's
+    ``limit_fn`` by identity), the budget-resolved cascade config with
+    its survivor-budget bucket, and the guard config; the fault seams the
+    pass reads while traced, by identity, so an injected fault never runs
+    a clean program; and the kernels' interpret mode."""
+
+    cascade: CascadeConfig
+    plan: VerificationPlan
+    k: int
+    dtw_fn: Callable
+    collect_stats: bool
+    guards: _g.GuardConfig
+    faults: tuple
+    interpret: bool | None
+
+
+# the fault seams the bound pass reads while it is traced (the seeds' DTW
+# op reads ``dtw_out``)
+_BOUND_SEAMS = ("tier_out", "compaction_cand", "packed_rows", "dtw_out")
+
+
 def _shapes(*arrays) -> tuple:
-    return tuple((tuple(x.shape), x.dtype)
-                 for x in jax.tree_util.tree_leaves(arrays))
+    leaves, tree = jax.tree_util.tree_flatten(arrays)
+    return tree, tuple((tuple(x.shape), x.dtype) for x in leaves)
 
 
-# warnings raised and ``repro.obs`` counts kept while a loop program was
-# traced (its DTW op's grid, a kernel fallback), by key and shapes:
-# ``_verify`` raises and counts them again on every call of the program
-_trace_warnings: dict = {}
-_trace_counts: dict = {}
+# warnings raised and ``repro.obs`` counts kept while a program was traced
+# (a kernel's grid, a kernel fallback), by key and argument shapes:
+# ``_call`` raises and counts them again on every call of the program
+_traced: dict = {}
 _warn_registry: dict = {}
 
 
-def _verify(key: _LoopKey, q, series, order, slb, slb_pad, state):
-    """Run the verification loop through its cached program, raising the
-    warnings its tracing raised (a kernel fallback among them) and
-    counting the ops its tracing recorded, each time."""
-    out = _verify_loop(key, q, series, order, slb, slb_pad, state)
-    traced = (key, _shapes(q, series, order, slb, slb_pad, state))
-    for w in _trace_warnings.get(traced, ()):
+def _program(body):
+    """``body(key, *arrays)`` as one jitted program per static key and
+    argument shapes, keeping what its tracing warned and counted for
+    ``_call``."""
+
+    def traced(key, *args):
+        with warnings.catch_warnings(record=True) as caught, \
+                obs.recording() as counts:
+            warnings.simplefilter("always")
+            out = body(key, *args)
+        _traced[key, _shapes(*args)] = (caught, counts)
+        return out
+
+    return jax.jit(functools.wraps(body)(traced), static_argnums=0)
+
+
+def _call(program, key, *args):
+    """Run a cached ``_program``, raising the warnings its tracing raised
+    (a kernel fallback among them) and counting the ops its tracing
+    recorded, on every call."""
+    out = program(key, *args)
+    caught, counts = _traced.get((key, _shapes(*args)), ((), ()))
+    for w in caught:
         warnings.warn_explicit(w.message, w.category, w.filename, w.lineno,
                                registry=_warn_registry)
-    obs.replay(_trace_counts.get(traced, ()))
+    obs.replay(counts)
     return out
 
 
-@functools.partial(jax.jit, static_argnums=0)
+def _bounds(key: _BoundsKey, q, index, exclude):
+    """The bound pass: ``run_plan`` for a staged cascade, the dense tier
+    matrix otherwise."""
+    if not key.cascade.staged:
+        return compute_bounds(q, index, key.cascade, k=key.k, plan=key.plan)
+    return run_plan(q, index, key.cascade, key.plan, k=key.k,
+                    dtw_fn=key.dtw_fn, exclude=exclude,
+                    collect_stats=key.collect_stats, guards=key.guards)
+
+
+# Every array the pass reads is an argument, so the store is never a
+# constant of the program.
+_bound_pass = _program(_bounds)
+
+
+@_program
 def _verify_loop(key: _LoopKey, q, series, order, slb, slb_pad, state):
     """Bound-ordered rounds of banded DTW until every query certifies.
 
@@ -624,14 +680,7 @@ def _verify_loop(key: _LoopKey, q, series, order, slb, slb_pad, state):
         r, _, _, _, _, done, _ = state
         return (r < key.max_rounds) & ~jnp.all(done)
 
-    with warnings.catch_warnings(record=True) as caught, \
-            obs.recording() as counts:
-        warnings.simplefilter("always")
-        r, best_d, best_i, n_dtw, _, _, gacc = lax.while_loop(cond, body,
-                                                              state)
-    traced = (key, _shapes(q, series, order, slb, slb_pad, state))
-    _trace_warnings[traced] = caught
-    _trace_counts[traced] = counts
+    r, best_d, best_i, n_dtw, _, _, gacc = lax.while_loop(cond, body, state)
     return r, best_d, best_i, n_dtw, gacc
 
 
@@ -661,18 +710,32 @@ def _search(
     M = min(cfg.verify_chunk, N)
     g = _g.resolve_guards(cfg.guards)
     gon = g.enabled
+    if exclude is not None:
+        exclude = jnp.asarray(exclude)
     with obs.span("engine.bounds"):
         if cascade is None:
             cascade = _resolve_cascade(q, index, cfg.cascade, k, exclude,
                                        plan)
         dtw_fn = dtw_band_op if cascade.use_pallas else dtw_band_ref
-        if cascade.staged:
-            cres = run_plan(
-                q, index, cascade, plan, k=k, dtw_fn=dtw_fn,
-                exclude=exclude, collect_stats=collect_stats, guards=g,
-            )
+        bkey = _BoundsKey(
+            cascade=cascade, plan=plan, k=k, dtw_fn=dtw_fn,
+            collect_stats=collect_stats, guards=g,
+            faults=tuple(_g.fault_hook(s) for s in _BOUND_SEAMS),
+            interpret=_interpret() if cascade.use_pallas else None,
+        )
+        # concrete inputs run the cached program; traced ones (the
+        # distributed step under shard_map, a caller's jit) trace the
+        # pass inline into the caller's program
+        if _all_concrete(q, index, exclude):
+            obs.count("bound_pass", "program")
+            bres = _call(_bound_pass, bkey, q, index, exclude)
         else:
-            lb = compute_bounds(q, index, cascade, k=k, plan=plan)
+            obs.count("bound_pass", "inline")
+            bres = _bounds(bkey, q, index, exclude)
+        if cascade.staged:
+            cres = bres
+        else:
+            lb = bres
     w = cascade.w
 
     # ---- work-conserving flat verification scheduler -------------------
@@ -758,8 +821,8 @@ def _search(
         interpret=_interpret() if dtw_fn is dtw_band_op else None,
     )
     with obs.span("engine.verify"):
-        r, best_d, best_i, n_dtw, gacc = _verify(
-            key, q, index.series, order, slb, slb_pad, state)
+        r, best_d, best_i, n_dtw, gacc = _call(
+            _verify_loop, key, q, index.series, order, slb, slb_pad, state)
         obs.count_rounds(r)
     guard = None
     if gon:

@@ -412,6 +412,31 @@ def unregister_tier(name: str) -> bool:
     return _TIER_REGISTRY.pop(name, None) is not None
 
 
+# The built-in tiers' bound functions live at module level, so every
+# ``get_tier(name)`` returns an equal ``BoundTier``: plans built on
+# different calls compare equal and key one compiled bound pass
+# (engine.py ``_BoundsKey``) instead of one per call.
+
+
+def _sketch_bound(q, index, cfg):
+    import jax.numpy as jnp
+
+    if getattr(index, "sk_lo", None) is None:
+        return jnp.zeros((q.shape[0], index.n), jnp.float32)
+    from repro.kernels import ref as _ref
+    from repro.kernels.ops import sketch_bound_op
+    from repro.search.index import (
+        sketch_query_means,
+        sketch_segment_sizes,
+    )
+
+    s = index.sk_lo.shape[1]
+    qbar = sketch_query_means(q, s)
+    seg = sketch_segment_sizes(index.length, s)
+    op = sketch_bound_op if cfg.use_pallas else _ref.sketch_bound_ref
+    return op(qbar, index.sk_lo, index.sk_hi, index.sk_scale, seg)
+
+
 @register_tier("sketch")
 def _sketch_tier() -> BoundTier:
     """Tier -1: O(S)/pair quantised sketch bound from the int8 PAA
@@ -423,26 +448,26 @@ def _sketch_tier() -> BoundTier:
     (valid, idle, planner-dropped), which is what lets ``default_plan``
     include the tier without knowing the index.
     """
+    return BoundTier("sketch", cost="O(S)", scope="all_pairs",
+                     fn=_sketch_bound)
 
-    def fn(q, index, cfg):
-        import jax.numpy as jnp
 
-        if getattr(index, "sk_lo", None) is None:
-            return jnp.zeros((q.shape[0], index.n), jnp.float32)
-        from repro.kernels import ref as _ref
-        from repro.kernels.ops import sketch_bound_op
-        from repro.search.index import (
-            sketch_query_means,
-            sketch_segment_sizes,
-        )
+def _lb_improved_bound(qrows, crows, urows, lrows, cfg, *, live=None):
+    import jax.numpy as jnp
 
-        s = index.sk_lo.shape[1]
-        qbar = sketch_query_means(q, s)
-        seg = sketch_segment_sizes(index.length, s)
-        op = sketch_bound_op if cfg.use_pallas else _ref.sketch_bound_ref
-        return op(qbar, index.sk_lo, index.sk_hi, index.sk_scale, seg)
+    from repro.core.envelopes import envelope
+    from repro.core.lower_bounds import lb_keogh_env
 
-    return BoundTier("sketch", cost="O(S)", scope="all_pairs", fn=fn)
+    first = lb_keogh_env(qrows, urows, lrows)
+    proj = jnp.clip(qrows, lrows, urows)
+    up, lp = envelope(proj, cfg.w)
+    out = first + lb_keogh_env(crows, up, lp)
+    if live is not None:
+        liv = jnp.broadcast_to(
+            jnp.asarray(live), out.shape
+        ).astype(bool)
+        out = jnp.where(liv, out, float("-inf"))
+    return out
 
 
 @register_tier("lb_improved")
@@ -460,37 +485,26 @@ def _lb_improved_tier() -> BoundTier:
     prices it per store and keeps it only where the measured mass says
     the extra O(L) pass pays.
     """
+    return BoundTier("lb_improved", cost="O(L)", scope="pairwise",
+                     fn=_lb_improved_bound)
 
-    def fn(qrows, crows, urows, lrows, cfg, *, live=None):
-        import jax.numpy as jnp
 
-        from repro.core.envelopes import envelope
-        from repro.core.lower_bounds import lb_keogh_env
+def _kim_bound(q, index, cfg):
+    from repro.search.cascade import lb_kim_tier
 
-        first = lb_keogh_env(qrows, urows, lrows)
-        proj = jnp.clip(qrows, lrows, urows)
-        up, lp = envelope(proj, cfg.w)
-        out = first + lb_keogh_env(crows, up, lp)
-        if live is not None:
-            liv = jnp.broadcast_to(
-                jnp.asarray(live), out.shape
-            ).astype(bool)
-            out = jnp.where(liv, out, float("-inf"))
-        return out
-
-    return BoundTier("lb_improved", cost="O(L)", scope="pairwise", fn=fn)
+    return lb_kim_tier(q, index)
 
 
 @register_tier("kim")
 def _kim_tier() -> BoundTier:
     """O(1)/pair Kim bound from precomputed index features."""
+    return BoundTier("kim", cost="O(1)", scope="all_pairs", fn=_kim_bound)
 
-    def fn(q, index, cfg):
-        from repro.search.cascade import lb_kim_tier
 
-        return lb_kim_tier(q, index)
+def _bands_bound(q, index, cfg, *, live=None):
+    from repro.search.cascade import bands_prefilter
 
-    return BoundTier("kim", cost="O(1)", scope="all_pairs", fn=fn)
+    return bands_prefilter(q, index, cfg, live=live)
 
 
 @register_tier("bands")
@@ -499,38 +513,34 @@ def _bands_tier() -> BoundTier:
 
     Honours the store-level ``live`` mask (cross-block kernel liveness:
     dead candidates emit ``-inf``, fully-dead candidate tiles skip)."""
+    return BoundTier("bands", cost="O(V^2)", scope="all_pairs",
+                     fn=_bands_bound)
 
-    def fn(q, index, cfg, *, live=None):
-        from repro.search.cascade import bands_prefilter
 
-        return bands_prefilter(q, index, cfg, live=live)
-
-    return BoundTier("bands", cost="O(V^2)", scope="all_pairs", fn=fn)
+def _enhanced_pairwise_bound(qrows, crows, urows, lrows, cfg, *, live=None):
+    return cfg.pairwise_fn()(qrows, crows, urows, lrows, cfg.w, cfg.v,
+                             live=live)
 
 
 @register_tier("enhanced_pairwise")
 def _enhanced_pairwise_tier() -> BoundTier:
     """O(L)/pair fused LB_ENHANCED^V over the packed survivor rows."""
-
-    def fn(qrows, crows, urows, lrows, cfg, *, live=None):
-        return cfg.pairwise_fn()(qrows, crows, urows, lrows, cfg.w, cfg.v,
-                                 live=live)
-
     return BoundTier("enhanced_pairwise", cost="O(L)", scope="pairwise",
-                     fn=fn)
+                     fn=_enhanced_pairwise_bound)
+
+
+def _enhanced_dense_bound(q, index, cfg, *, live=None):
+    from repro.search.cascade import enhanced_all_pairs
+
+    return enhanced_all_pairs(q, index, cfg, live=live)
 
 
 @register_tier("enhanced_dense")
 def _enhanced_dense_tier() -> BoundTier:
     """O(L)/pair LB_ENHANCED^V on *all* pairs — the unstaged diagnostic
     tier (cross-block kernel shape), bypassing compaction entirely."""
-
-    def fn(q, index, cfg, *, live=None):
-        from repro.search.cascade import enhanced_all_pairs
-
-        return enhanced_all_pairs(q, index, cfg, live=live)
-
-    return BoundTier("enhanced_dense", cost="O(L)", scope="all_pairs", fn=fn)
+    return BoundTier("enhanced_dense", cost="O(L)", scope="all_pairs",
+                     fn=_enhanced_dense_bound)
 
 
 def default_plan(cfg, *, schedule: str = "bound") -> VerificationPlan:

@@ -28,9 +28,8 @@ BOUND_KERNELS = (lb_keogh_pallas, lb_enhanced_pallas,
 
 
 def _clear_programs():
-    for fn in BOUND_KERNELS:
+    for fn in (*BOUND_KERNELS, engine._bound_pass, engine._verify_loop):
         fn.clear_cache()
-    engine._verify_loop.clear_cache()
 
 
 @pytest.fixture

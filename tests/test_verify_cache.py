@@ -53,11 +53,13 @@ def _exact(idx, q, res, k):
 
 @pytest.fixture(autouse=True)
 def _fresh_programs():
-    # each test starts without the loop's programs, and leaves none of
-    # its own (a monkeypatched kernel limit is not part of the key)
-    engine._verify_loop.clear_cache()
+    # each test starts without the engine's programs, and leaves none of
+    # its own (a monkeypatched kernel limit is not part of a key)
+    for program in (engine._bound_pass, engine._verify_loop):
+        program.clear_cache()
     yield
-    engine._verify_loop.clear_cache()
+    for program in (engine._bound_pass, engine._verify_loop):
+        program.clear_cache()
 
 
 def test_two_calls_of_one_shape_lower_once():
